@@ -36,6 +36,7 @@ from benchmarks import (
     table5_comm_costs,
 )
 from benchmarks._common import emit
+from repro import compile_cache
 
 SUITE = {
     "fig3": (fig3_cache_sim, {}),
@@ -83,6 +84,7 @@ def main() -> None:
     args = ap.parse_args()
 
     names = [n.strip() for n in args.only.split(",") if n.strip()] or list(SUITE)
+    print(f"# compile cache: {compile_cache.enable()}", file=sys.stderr)
     print("name,us_per_call,derived")
     failures = 0
     for name in names:

@@ -13,7 +13,7 @@ Three passes, one CLI (``python -m repro.analysis``):
     walks the shard engine's one-round ``shard_map`` jaxpr tracking
     ``axis_index`` / sharded-input taint to prove every carry leaf the
     out_specs declare replicated really is replicated over non-client
-    mesh axes (the engine runs ``check_rep=False``, so nothing else
+    mesh axes (the engine runs ``check_vma=False``, so nothing else
     checks this — the PR 5 ``last_sync`` bug class).
 
 ``pallas_checks``

@@ -272,14 +272,6 @@ def async_staleness_callback_engine():
 # Replication fixtures
 # ---------------------------------------------------------------------------
 
-def _shard_map():
-    try:
-        from jax import shard_map as f
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as f
-    return f
-
-
 def _fixture_mesh():
     from repro.launch.mesh import make_test_mesh
     return make_test_mesh(2, 4)
@@ -297,8 +289,8 @@ def broken_carry_fn():
         part_local = (jnp.arange(kloc) + t + six) % 2 > 0  # shard-varying
         return jnp.where(part_local, t, last_sync)
 
-    fn = _shard_map()(body, mesh=mesh, in_specs=(P(), P()),
-                      out_specs=P(), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=P(), check_vma=False)
     abstract = (jax.ShapeDtypeStruct((8,), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32))
     return fn, abstract
@@ -318,8 +310,8 @@ def fixed_carry_fn():
             part_local.astype(jnp.int32), ("data", "model")) > 0
         return jnp.where(part_global, t, last_sync)
 
-    fn = _shard_map()(body, mesh=mesh, in_specs=(P(), P()),
-                      out_specs=P(), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=P(), check_vma=False)
     abstract = (jax.ShapeDtypeStruct((8,), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32))
     return fn, abstract

@@ -66,8 +66,10 @@ def _is_smem(bm) -> bool:
 
 
 def _block_dims(bm) -> Tuple[int, ...]:
-    return tuple(int(d) if isinstance(d, int) else 1
-                 for d in bm.block_shape)
+    """Block extent per dim: ``Blocked(block_size=n)`` -> n, squeezed
+    dims -> 1."""
+    dims = (getattr(d, "block_size", d) for d in bm.block_shape)
+    return tuple(int(d) if isinstance(d, int) else 1 for d in dims)
 
 
 def check_case(label: str, fn, args) -> List[Finding]:
@@ -94,7 +96,7 @@ def check_case(label: str, fn, args) -> List[Finding]:
         for i, bm in enumerate(gm.block_mappings):
             is_input = i < gm.num_inputs
             kind = "in" if is_input else "out"
-            arr = bm.array_shape_dtype
+            arr = bm.array_aval
             dims = _block_dims(bm)
             if _is_smem(bm):
                 continue  # scalar memory: no tiling/VMEM constraints

@@ -1,7 +1,7 @@
 """Pass 2: prove shard_map replication claims by axis taint analysis.
 
 The client-sharded engine wraps its round body in ``shard_map(...,
-check_rep=False)`` — the scan carry defeats the partitioner's own
+check_vma=False)`` — the scan carry defeats the partitioner's own
 replication inference, so *nothing* verifies that carry leaves declared
 ``P()`` (replicated) really are bit-identical across shards.  A leaf
 that silently varies per shard (the PR 5 ``last_sync`` bug: an update
@@ -11,7 +11,7 @@ gather at scan exit.
 This pass walks the shard_map body jaxpr with a standard taint
 interpreter over mesh axis names:
 
-- an input sharded over axis ``a`` (``in_names`` mentions ``a``) is
+- an input sharded over axis ``a`` (``in_specs`` mention ``a``) is
   tainted by ``a`` — its values differ across ``a``-shards;
 - ``axis_index(a)`` introduces taint ``{a}`` from nothing;
 - reducing collectives over ``a`` (``psum``/``pmax``/``pmin``/
@@ -20,7 +20,7 @@ interpreter over mesh axis names:
 - everything else unions its input taints; control flow recurses
   (scan/while to fixpoint, cond unions branches + predicate taint).
 
-An output whose ``out_names`` omit axis ``a`` (claiming replication
+An output whose ``out_specs`` omit axis ``a`` (claiming replication
 over ``a``) but whose taint contains ``a`` is a proven contract
 violation: **error**.
 """
@@ -29,6 +29,7 @@ from __future__ import annotations
 from typing import Dict, FrozenSet, List, Sequence, Tuple
 
 import jax
+from jax.extend import core as jcore
 
 from repro.analysis.report import Finding
 
@@ -56,12 +57,12 @@ def _axes_param(v) -> Tuple[str, ...]:
 
 
 def _read(env: Dict, atom) -> Taint:
-    if isinstance(atom, jax.core.Literal):
+    if isinstance(atom, jcore.Literal):
         return _EMPTY
     return env.get(atom, _EMPTY)
 
 
-def taint_jaxpr(jaxpr: jax.core.Jaxpr,
+def taint_jaxpr(jaxpr: jcore.Jaxpr,
                 in_taints: Sequence[Taint]) -> List[Taint]:
     """Propagate axis taints through ``jaxpr``; returns output taints."""
     env: Dict = {}
@@ -106,7 +107,7 @@ def taint_jaxpr(jaxpr: jax.core.Jaxpr,
 
 def _inner_jaxpr(e):
     j = e.params.get("jaxpr") or e.params.get("call_jaxpr")
-    if isinstance(j, jax.core.ClosedJaxpr):
+    if isinstance(j, jcore.ClosedJaxpr):
         return j.jaxpr
     return j
 
@@ -155,11 +156,15 @@ def _taint_cond(e, ins: List[Taint]) -> List[Taint]:
 # shard_map-level check
 # ---------------------------------------------------------------------------
 
-def _names_taint(names: dict) -> Taint:
-    """in_names/out_names entry -> axes the value varies over."""
+def _spec_taint(spec) -> Taint:
+    """in_specs/out_specs entry (a PartitionSpec) -> axes the value
+    varies over."""
     out = set()
-    for axes in names.values():
-        out.update(str(a) for a in axes)
+    for axes in spec:
+        if axes is None:
+            continue
+        out.update(str(a) for a in
+                   (axes if isinstance(axes, (tuple, list)) else (axes,)))
     return frozenset(out)
 
 
@@ -181,16 +186,16 @@ def check_shard_map_fn(fn, abstract_args, pass_name: str = "replication",
         return findings
     e = eqns[0]
     inner = e.params["jaxpr"]
-    in_names, out_names = e.params["in_names"], e.params["out_names"]
+    in_specs, out_specs = e.params["in_specs"], e.params["out_specs"]
     mesh_axes = tuple(str(a) for a in e.params["mesh"].shape)
 
-    in_taints = [_names_taint(n) for n in in_names]
+    in_taints = [_spec_taint(s) for s in in_specs]
     out_taints = taint_jaxpr(inner, in_taints)
 
     labels = _output_labels(fn, abstract_args, len(out_taints))
     ok = True
-    for i, (taint, names) in enumerate(zip(out_taints, out_names)):
-        declared = _names_taint(names)
+    for i, (taint, spec) in enumerate(zip(out_taints, out_specs)):
+        declared = _spec_taint(spec)
         leaked = (taint - declared) & frozenset(mesh_axes)
         if leaked:
             ok = False
@@ -198,7 +203,7 @@ def check_shard_map_fn(fn, abstract_args, pass_name: str = "replication",
                 "error", pass_name, f"{subject_prefix}{labels[i]}",
                 f"declared replicated over axes {sorted(leaked)} but the "
                 f"carry update is tainted by them (taint={sorted(taint)}, "
-                f"out_names={names}) — shards will disagree at the gather"))
+                f"out_specs={spec}) — shards will disagree at the gather"))
     if ok:
         findings.append(Finding(
             "ok", pass_name, subject_prefix or "shard_map",

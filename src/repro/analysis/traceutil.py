@@ -12,6 +12,7 @@ from typing import Iterator, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.extend import core as jcore
 
 # Primitives that escape the traced graph back to the host: fatal inside
 # lax.scan (the scan-safe contract) and invisible to AOT cost models.
@@ -21,17 +22,17 @@ CALLBACK_PRIMITIVES = frozenset({
 })
 
 
-def subjaxprs(eqn) -> Iterator[jax.core.Jaxpr]:
+def subjaxprs(eqn) -> Iterator[jcore.Jaxpr]:
     """Immediate sub-jaxprs of one equation (scan/cond/while/pjit/...)."""
     for v in eqn.params.values():
         for x in (v if isinstance(v, (tuple, list)) else [v]):
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, jcore.ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, jcore.Jaxpr):
                 yield x
 
 
-def iter_eqns(jaxpr: jax.core.Jaxpr):
+def iter_eqns(jaxpr: jcore.Jaxpr):
     """Every equation in ``jaxpr``, recursing through sub-jaxprs."""
     for e in jaxpr.eqns:
         yield e
@@ -39,11 +40,11 @@ def iter_eqns(jaxpr: jax.core.Jaxpr):
             yield from iter_eqns(sub)
 
 
-def primitive_names(jaxpr: jax.core.Jaxpr) -> set:
+def primitive_names(jaxpr: jcore.Jaxpr) -> set:
     return {e.primitive.name for e in iter_eqns(jaxpr)}
 
 
-def find_eqns(jaxpr: jax.core.Jaxpr, name: str) -> List:
+def find_eqns(jaxpr: jcore.Jaxpr, name: str) -> List:
     return [e for e in iter_eqns(jaxpr) if e.primitive.name == name]
 
 

@@ -64,7 +64,10 @@ from repro.core import cache as cache_lib
 from repro.core import comm as comm_lib
 from repro.kernels import round_kernel
 from repro.obs import device as obs_device
-from repro.fl.scan_engine import ScannedFederatedDistillation
+from repro.fl.scan_engine import (
+    ScannedFederatedDistillation,
+    compiler_options,
+)
 from repro.fl.rounds import (
     FederatedDistillation,
     History,
@@ -108,8 +111,11 @@ class ActiveSetFederatedDistillation(ScannedFederatedDistillation):
         self._eval_chunk = eval_chunk
         self._last_sync_dev = None
         super().__init__(*args, **kwargs)
-        self._client_step_jit = jax.jit(self._client_step)
-        self._bookkeeping_jit = jax.jit(self._bookkeeping_step)
+        opts = compiler_options()
+        self._client_step_jit = jax.jit(self._client_step,
+                                        compiler_options=opts)
+        self._bookkeeping_jit = jax.jit(self._bookkeeping_step,
+                                        compiler_options=opts)
 
     # ------------------------------------------------------------------
     # Placement hooks (rounds.py): per-client state stays host numpy.

@@ -57,11 +57,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 re-exports it at the top level
-    from jax import shard_map as _shard_map_fn
-except ImportError:  # pragma: no cover - version-dependent import path
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
 from repro.core import cache as cache_lib
 from repro.core import comm as comm_lib
 from repro.obs import device as obs_device
@@ -76,7 +71,10 @@ from repro.fl.rounds import (
     val_loss_hard_v,
     val_loss_soft,
 )
-from repro.fl.scan_engine import ScannedFederatedDistillation
+from repro.fl.scan_engine import (
+    ScannedFederatedDistillation,
+    compiler_options,
+)
 from repro.fl.strategies.base import TRANSMIT_SALT
 from repro.kernels import round_kernel
 from repro.launch.mesh import (
@@ -193,7 +191,7 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
         # last_sync stays REPLICATED: its update depends only on the
         # (replicated) global participation draw, so keeping it global
         # avoids axis_index-tainted dataflow in an int carry — which the
-        # SPMD partitioner (check_rep=False) cannot prove replicated
+        # SPMD partitioner (check_vma=False) cannot prove replicated
         # over non-client mesh axes and would mis-reduce on the gather.
         carry = dict(
             client_params=cax, server_params=rep, cache=rep,
@@ -460,10 +458,11 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
                 lambda s: NamedSharding(self.mesh, s), in_specs,
                 is_leaf=lambda x: isinstance(x, P))
             self._shard_fn = jax.jit(
-                _shard_map_fn(scan_all, mesh=self.mesh, in_specs=in_specs,
+                jax.shard_map(scan_all, mesh=self.mesh, in_specs=in_specs,
                               out_specs=(carry_specs, P()),
-                              check_rep=False),
-                in_shardings=shardings)
+                              check_vma=False),
+                in_shardings=shardings,
+                compiler_options=compiler_options())
         return self._shard_fn
 
     def _aot_args(self, ts, offline, do_eval):
@@ -477,7 +476,7 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
         static replication checker
         (:mod:`repro.analysis.replication_checks`).
 
-        The round program runs with ``check_rep=False`` (the scan body
+        The round program runs with ``check_vma=False`` (the scan body
         defeats the partitioner's replication inference), so nothing at
         compile time verifies that the carry leaves ``_specs()``
         declares replicated (``P()``) really stay bit-identical across
@@ -487,11 +486,11 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
         analysis instead.
         """
         carry_specs, xs_specs, consts_specs = self._specs()
-        fn = _shard_map_fn(
+        fn = jax.shard_map(
             lambda carry, xs, consts: self._round_device_sharded(
                 carry, xs, consts),
             mesh=self.mesh, in_specs=(carry_specs, xs_specs, consts_specs),
-            out_specs=(carry_specs, P()), check_rep=False)
+            out_specs=(carry_specs, P()), check_vma=False)
         abstract = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
             (self._initial_carry(),

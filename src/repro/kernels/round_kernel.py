@@ -10,14 +10,17 @@ three-plus times per round; this kernel streams each row block through
 VMEM exactly once — codec, reduction, and sharpening applied back to
 back while the block is resident.
 
-Per ``m``-row block the kernel sees the full client axis
-(``(K, bm, Np)`` BlockSpec, like ``era_kernel.enhanced_era_fused``), so
-the client reduction completes inside the block and the sharpening
-nonlinearity can fuse behind it.  ``bm`` is auto-shrunk to a VMEM
-budget as K grows (the K axis is resident per block) and kept 8-aligned
-(``runtime.align_block_rows``); the class dim is padded to 128 lanes
-and masked in-kernel with ``broadcasted_iota`` lane predicates, exactly
-as in the per-op kernels it replaces.
+The grid is (row blocks, client chunks).  Each step holds a
+``(kc, bm, Np)`` slab; the client chunks are the inner reduction axis,
+summed into a ``(bm, Np)`` VMEM accumulator, and the sharpening
+nonlinearity runs behind the last chunk.  ``_auto_blocks`` sizes ``kc``
+and ``bm`` (both 8-aligned, ``runtime.align_block_rows``) so the
+step's whole live set — input buffers plus the codec's temporaries —
+fits the core's scoped VMEM; the quant and delta branches keep several
+slab-sized temporaries live, so at K >= 100 an unchunked client axis
+does not fit.  The class dim is padded to 128 lanes and masked in-kernel
+with ``broadcasted_iota`` lane predicates, exactly as in the per-op
+kernels it replaces.
 
 Codec modes (must mirror ``repro.compress.codecs`` bit for bit — the
 engines' comm ledger is analytic, so values may drift only within one
@@ -55,7 +58,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.runtime import (
     SUBLANES_F32,
     VMEM_BUDGET_INTERPRET,
-    VMEM_BUDGET_NATIVE,
+    VMEM_LIMIT_NATIVE,
     align_block_rows,
     fit_block_rows,
     resolve_interpret,
@@ -72,12 +75,18 @@ MODES = ("identity", "quant", "delta")
 # beta rides in SMEM as a (1,) array (scalar memory; see era_kernel)
 _BETA_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-# VMEM budget for the (K, bm, Np) block: the K axis is resident per
-# block, so bm must shrink as K grows.  Shared constants in
-# kernels/runtime.py (era_kernel's fused path sizes against the same
-# budget, and repro.analysis.pallas_checks lints against the limit).
-_VMEM_BUDGET_NATIVE = VMEM_BUDGET_NATIVE
-_VMEM_BUDGET_INTERPRET = VMEM_BUDGET_INTERPRET
+# VMEM budget for one grid step's whole live set (see _auto_blocks):
+# three quarters of a core's scoped VMEM natively; the interpreter has
+# no VMEM, so its budget only sets the number of grid steps.
+_VMEM_LIVE_BUDGET_NATIVE = VMEM_LIMIT_NATIVE * 3 // 4
+_VMEM_LIVE_BUDGET_INTERPRET = VMEM_BUDGET_INTERPRET
+
+# Slab-sized f32 arrays live at once per grid step, by codec mode: the
+# double-buffered input plus the branch's temporaries.  Measured by
+# compiling for a v5e chip, where Mosaic reports the scoped VMEM a
+# failing kernel needs: about 2.1 slabs for identity and 7.3 for quant
+# and delta (N=10, 2.5 MiB slab); rounded up.
+_LIVE_SLABS = {"identity": 3, "quant": 8, "delta": 8}
 
 
 def _qdq(r, valid, levels):
@@ -104,10 +113,13 @@ def _fused_round_kernel(*refs, k_clients: int, n_valid: int,
     z_ref, w_ref = next(it), next(it)
     base_ref = next(it) if mode == "delta" else None
     beta_ref = next(it) if sharpen else None
-    o_ref = next(it)
+    o_ref, acc_ref = next(it), next(it)
+    k = pl.program_id(1)
 
-    z = z_ref[...].astype(jnp.float32)                   # (K, bm, Np)
-    lane = jax.lax.broadcasted_iota(jnp.int32, z.shape, 2)
+    z = z_ref[...].astype(jnp.float32)                   # (kc, bm, Np)
+    # lane predicates at (1, 1, Np): they broadcast against the block,
+    # so no block-sized int32 temporary stays live in VMEM
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, z.shape[2]), 2)
     valid = lane < n_valid
 
     if mode == "delta":
@@ -125,24 +137,53 @@ def _fused_round_kernel(*refs, k_clients: int, n_valid: int,
     else:
         z = jnp.where(valid, z, 0.0)
 
-    w = w_ref[...].astype(jnp.float32)                   # (K, 1)
-    zsum = jnp.sum(z * w[:, :, None], axis=0)            # (bm, Np)
-    if sharpen:
-        # identical to era_kernel._era_fused_kernel on the weighted stack
-        zbar = zsum / k_clients
-        beta = beta_ref[0]
-        logz = jnp.log(jnp.maximum(zbar, _EPS_ERA)) * beta
-        m = jnp.max(logz, axis=-1, keepdims=True)
-        e = jnp.exp(logz - m)
-        out = e / jnp.sum(e, axis=-1, keepdims=True)
-    else:
-        out = zsum
-    o_ref[...] = out.astype(o_ref.dtype)
+    w = w_ref[...].astype(jnp.float32)                   # (kc, 1)
+    part = jnp.sum(z * w[:, :, None], axis=0)            # (bm, Np)
+
+    # client chunks are the grid's inner (reduction) axis: the first
+    # chunk stores its partial sum, later chunks add theirs
+    @pl.when(k == 0)
+    def _():
+        acc_ref[...] = part
+
+    @pl.when(k > 0)
+    def _():
+        acc_ref[...] += part
+
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _():
+        zsum = acc_ref[...]
+        if sharpen:
+            # identical to era_kernel._era_fused_kernel on the weighted stack
+            zbar = zsum / k_clients
+            beta = beta_ref[0]
+            logz = jnp.log(jnp.maximum(zbar, _EPS_ERA)) * beta
+            m = jnp.max(logz, axis=-1, keepdims=True)
+            e = jnp.exp(logz - m)
+            out = e / jnp.sum(e, axis=-1, keepdims=True)
+        else:
+            out = zsum
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _auto_block_m(m: int, k: int, n_padded: int, interpret: bool) -> int:
-    budget = _VMEM_BUDGET_INTERPRET if interpret else _VMEM_BUDGET_NATIVE
-    return fit_block_rows(128, m, k * n_padded * 4, budget)
+def _auto_blocks(m: int, k: int, n_padded: int, mode: str,
+                 interpret: bool) -> tuple[int, int]:
+    """(row block ``bm``, client chunk ``kc``) for an (m, k) problem.
+
+    One grid step holds a ``(kc, bm, Np)`` slab of the client stack and
+    keeps ``_LIVE_SLABS[mode]`` slab-sized f32 arrays live in VMEM.  The
+    client axis is cut into as few equal 8-aligned chunks as fit the
+    budget at an 8-row block; rows then grow (up to 128) while the
+    chunk still fits.
+    """
+    budget = _VMEM_LIVE_BUDGET_INTERPRET if interpret else _VMEM_LIVE_BUDGET_NATIVE
+    per_client_row = n_padded * 4 * _LIVE_SLABS[mode]
+    kc_max = max(SUBLANES_F32, budget // (SUBLANES_F32 * per_client_row)
+                 // SUBLANES_F32 * SUBLANES_F32)
+    n_chunks = -(-k // kc_max)
+    kc = align_block_rows(-(-k // n_chunks), k)
+    bm = fit_block_rows(128, m, kc * per_client_row, budget)
+    return bm, kc
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "bits", "sharpen",
@@ -157,8 +198,8 @@ def fused_round(z_clients: jnp.ndarray, weights: jnp.ndarray, beta=None,
     ``weights`` is the (K,) per-client reduction weight (see module
     docs); ``base`` is the *resolved* delta base (``(m, N)``, required
     for ``mode="delta"`` — use :func:`resolve_delta_base`).  ``beta`` is
-    required when ``sharpen=True``.  ``interpret=None`` auto-detects the
-    backend.
+    required when ``sharpen=True``.  ``block_m`` overrides the auto-sized
+    row block.  ``interpret=None`` auto-detects the backend.
     """
     if mode not in MODES:
         raise ValueError(f"unknown mode {mode!r} (want one of {MODES})")
@@ -173,15 +214,18 @@ def fused_round(z_clients: jnp.ndarray, weights: jnp.ndarray, beta=None,
     K, M, N = z_clients.shape
     n_pad = (-N) % 128
     Np = N + n_pad
-    # The client axis is padded to the sublane tile: the (K, 1) weights
+    # The client axis is padded to the sublane tile: the (kc, 1) weights
     # operand makes K a *sublane* dim, so an unaligned client count
     # (e.g. K=50) mis-tiles natively — caught by the static BlockSpec
-    # lint (repro.analysis.pallas_checks).  Padded clients carry zero
-    # weight, so the reduction (and the /K mean) is unchanged.
-    k_pad = (-K) % SUBLANES_F32
+    # lint (repro.analysis.pallas_checks).  It is further padded to a
+    # whole number of client chunks.  Padded clients carry zero weight,
+    # so the reduction (and the /K mean) is unchanged.
+    K8 = align_block_rows(K, K)
+    bm, kc = _auto_blocks(M, K8, Np, mode, interpret)
+    if block_m is not None:
+        bm = align_block_rows(block_m, M)
+    k_pad = (-K) % kc
     Kp = K + k_pad
-    bm = (align_block_rows(block_m, M) if block_m is not None
-          else _auto_block_m(M, Kp, Np, interpret))
     m_pad = (-M) % bm
     z = jnp.pad(z_clients, ((0, k_pad), (0, m_pad), (0, n_pad)))
     Mp = M + m_pad
@@ -191,13 +235,13 @@ def fused_round(z_clients: jnp.ndarray, weights: jnp.ndarray, beta=None,
 
     operands = [z, w]
     in_specs = [
-        pl.BlockSpec((Kp, bm, Np), lambda i: (0, i, 0)),
-        pl.BlockSpec((Kp, 1), lambda i: (0, 0)),
+        pl.BlockSpec((kc, bm, Np), lambda i, k: (k, i, 0)),
+        pl.BlockSpec((kc, 1), lambda i, k: (k, 0)),
     ]
     if mode == "delta":
         operands.append(jnp.pad(base.astype(jnp.float32),
                                 ((0, m_pad), (0, n_pad))))
-        in_specs.append(pl.BlockSpec((bm, Np), lambda i: (i, 0)))
+        in_specs.append(pl.BlockSpec((bm, Np), lambda i, k: (i, 0)))
     if sharpen:
         operands.append(jnp.asarray([beta], jnp.float32))
         in_specs.append(_BETA_SPEC)
@@ -205,10 +249,13 @@ def fused_round(z_clients: jnp.ndarray, weights: jnp.ndarray, beta=None,
     out = pl.pallas_call(
         functools.partial(_fused_round_kernel, k_clients=K, n_valid=N,
                           levels=levels, mode=mode, sharpen=sharpen),
-        grid=(Mp // bm,),
+        grid=(Mp // bm, Kp // kc),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, Np), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, Np), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), z_clients.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, Np), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
     return out[:M, :N]

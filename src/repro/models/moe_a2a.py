@@ -135,14 +135,12 @@ def moe_ffn_a2a(
         out = jnp.sum((contrib * gate_f[:, None]).reshape(T, top_k, D), axis=1)
         return out.reshape(Bl, S, D), aux
 
-    from jax.experimental.shard_map import shard_map
-
     w1_spec = P(axis_name, None, model_axis)
     w2_spec = P(axis_name, model_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(axis_name), P(), w1_spec, w1_spec, w2_spec),
         out_specs=(P(axis_name), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, router, w1, w3, w2)

@@ -9,9 +9,12 @@ round — fails here even when cross-engine conformance still holds
 (conformance compares engines to each other; the goldens pin the
 absolute values the paper's tables are computed from).
 
-The committed fixtures were generated from the pre-cohort engines, so
-they simultaneously pin the cohort refactor's homogeneous-path
-byte-compatibility.
+The participation draw behind every fixture comes from JAX's default
+PRNG.  Since JAX 0.5 that is the partitionable threefry
+(``jax_threefry_partitionable=True``), which draws different bits than
+the older default; the fixtures were regenerated under it and hold on
+any backend, device count and process history (``chip_smoke.py``
+compares them on the chip).
 
 Intentional changes: regenerate with
 
@@ -42,11 +45,14 @@ CODECS = ("identity", "quant8")
 CASES = [(m, c) for m in sorted(METHOD_KW) for c in CODECS]
 
 
-def _summary_text(method: str, codec: str) -> str:
+def summary_text(method: str, codec: str, **run_kw) -> str:
+    """Canonical ledger summary of one golden cell (``run_kw`` adds
+    ``run_method`` options, e.g. ``fused_round=True``); also what
+    ``chip_smoke.py`` compares with the fixtures on the chip."""
     h = run_method(
         method, CFG, engine="scan", codec=codec,
         scenario=Scenario(participation=bernoulli_participation(0.5)),
-        **METHOD_KW[method])
+        **run_kw, **METHOD_KW[method])
     return json.dumps(h.ledger.summary(), sort_keys=True, indent=2) + "\n"
 
 
@@ -54,7 +60,7 @@ def _summary_text(method: str, codec: str) -> str:
                          ids=[f"{m}-{c}" for m, c in CASES])
 def test_golden_ledger(method, codec, request):
     path = GOLDEN_DIR / f"{method}-{codec}.json"
-    text = _summary_text(method, codec)
+    text = summary_text(method, codec)
     if request.config.getoption("--update-golden"):
         path.write_text(text)
         pytest.skip(f"updated {path.name}")
@@ -76,12 +82,8 @@ def test_golden_ledger_fused_round_byte_identical(codec):
     fusing the compute hot path may not move a single byte.  No separate
     fused fixtures exist on purpose — the per-op files are the contract."""
     path = GOLDEN_DIR / f"scarlet-{codec}.json"
-    h = run_method(
-        "scarlet", CFG, engine="scan", codec=codec, fused_round=True,
-        scenario=Scenario(participation=bernoulli_participation(0.5)),
-        **METHOD_KW["scarlet"])
-    text = json.dumps(h.ledger.summary(), sort_keys=True, indent=2) + "\n"
-    assert path.read_text() == text
+    assert path.read_text() == summary_text("scarlet", codec,
+                                            fused_round=True)
 
 
 def test_no_stale_golden_fixtures():

@@ -53,17 +53,25 @@ def test_fused_round_matches_oracle(K, M, N, mode, bits, sharpen):
 
 
 def test_fused_round_block_sizing_auto_shrinks():
-    """Large K must shrink the row block against the VMEM budget while
-    staying 8-aligned — and still match the oracle."""
+    """Large K must be cut into 8-aligned client chunks against the VMEM
+    budget (rows stay 8-aligned) — and the chunked reduction must still
+    match the oracle."""
     K, M, N = 1000, 24, 10
     z = _probs(KEY, (K, M, N))
     w = jnp.ones(K)
-    bm = round_kernel._auto_block_m(M, K, 128, True)
-    assert bm % 8 == 0 and bm >= 8
-    out = round_kernel.fused_round(z, w, 1.5, mode="identity")
-    exp = ref.fused_round(z, w, 1.5, mode="identity")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
-                               rtol=1e-5, atol=1e-6)
+    for mode in round_kernel.MODES:
+        bm, kc = round_kernel._auto_blocks(M, K, 128, mode, False)
+        assert bm % 8 == 0 and bm >= 8
+        assert kc % 8 == 0 and 8 <= kc <= K
+        if mode != "identity":  # the codec temporaries force chunks
+            assert kc < K
+    # the interpreter's budget chunks quant at this K too
+    assert round_kernel._auto_blocks(M, K, 128, "quant", True)[1] < K
+    for mode, bits in (("identity", None), ("quant", 8)):
+        out = round_kernel.fused_round(z, w, 1.5, mode=mode, bits=bits)
+        exp = ref.fused_round(z, w, 1.5, mode=mode, bits=bits)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                                   rtol=1e-5, atol=1e-6)
 
 
 def test_fused_round_validation_errors():
