@@ -1,0 +1,105 @@
+"""The system under test, as one cell drives it.
+
+This is the only module of the benchmark that imports the program
+(``repro``).  It builds the engine that ``repro.fl.api.run_method``
+builds for the cell's configuration and traffic mix, and reads the
+state the correctness comparison needs.  The window drives the engine
+through its public ``run(R)``, which continues the same federation from
+the last round it finished.
+"""
+from __future__ import annotations
+
+from typing import Any, Dict
+
+import numpy as np
+
+FL_KEYS = ("n_clients", "n_classes", "dim", "hidden", "mlp_depth", "local_steps",
+           "distill_steps", "lr", "lr_dist", "public_size", "public_per_round",
+           "private_size", "cluster_scale", "noise", "partition", "uplink_codec",
+           "downlink_codec", "index_bytes")
+
+
+def engine_seed(seed: int) -> int:
+    """The run's seed as the program takes it: a non-negative int32."""
+    return int(seed) % (2 ** 31 - 1)
+
+
+def participation_rate(config: Dict[str, Any], traffic: Dict[str, Any]) -> float:
+    from chipbench import cost
+    return cost.participants(config, traffic) / config["n_clients"]
+
+
+def build_engine(config: Dict[str, Any], traffic: Dict[str, Any], seed: int):
+    """The engine for one cell, constructed as ``run_method`` does.  The
+    program has no public constructor that ``run_method`` shares, so this
+    repeats its construction from the same engine table."""
+    from repro.fl import FLConfig, Scenario, fixed_fraction, full_participation
+    from repro.fl.api import _ENGINES
+    from repro.fl.strategies import STRATEGIES
+
+    rate = participation_rate(config, traffic)
+    kw = {k: config[k] for k in FL_KEYS}
+    cfg = FLConfig(seed=engine_seed(seed), eval_every=traffic["eval_every"],
+                   rounds=traffic["rounds_per_call"], participation=rate,
+                   fused_round=bool(traffic["fused_round"]), **kw)
+    part = full_participation() if rate >= 1.0 else fixed_fraction(rate)
+    strategy = STRATEGIES[config["method"]](beta=config["beta"])
+    cls = _ENGINES[traffic["engine"]]
+    return cls(cfg, strategy, cache_duration=config["cache_duration"],
+               scenario=Scenario(participation=part))
+
+
+def client_leaves(engine, copy: bool = False) -> Dict[str, np.ndarray]:
+    """The client parameter stack on the host, by leaf name.  ``copy``
+    where the engine may overwrite it later."""
+    import jax
+    (stack,) = engine.client_params  # one homogeneous cohort
+    return {k: np.array(v, copy=copy) for k, v in jax.device_get(stack).items()}
+
+
+def server_leaves(engine) -> Dict[str, np.ndarray]:
+    import jax
+    return {k: np.asarray(v) for k, v in jax.device_get(engine.server_params).items()}
+
+
+def cache_arrays(engine):
+    import jax
+    c = jax.device_get(engine.cache_g)
+    return np.asarray(c.values), np.asarray(c.ts), np.asarray(c.present)
+
+
+def history_record(hist) -> Dict[str, Any]:
+    """Ledger and eval rows of one ``run(R)`` call."""
+    evals = {}
+    for i, t in enumerate(hist.rounds):
+        evals[int(t)] = {"server_acc": hist.server_acc[i],
+                         "client_acc": hist.client_acc[i],
+                         "client_val_loss": hist.client_val_loss[i]}
+        if i < len(hist.server_val_loss):
+            evals[int(t)]["server_val_loss"] = hist.server_val_loss[i]
+    return {"uplink": [r.uplink for r in hist.ledger.rounds],
+            "downlink": [r.downlink for r in hist.ledger.rounds],
+            "evals": evals}
+
+
+def reference_setting(config: Dict[str, Any], traffic: Dict[str, Any]):
+    from chipbench.reference import Setting
+    codec = config["uplink_codec"]
+    if not codec.startswith("cache_delta+quant") or config["downlink_codec"] != "identity":
+        raise ValueError(f"the reference models cache_delta+quantB uplink and an "
+                         f"identity downlink, not {codec!r}/{config['downlink_codec']!r}")
+    if config["partition"] != "uniform" or config["method"] != "scarlet":
+        raise ValueError("the reference models SCARLET on the uniform partition")
+    from chipbench import cost
+    return Setting(
+        n_clients=config["n_clients"], n_classes=config["n_classes"], dim=config["dim"],
+        hidden=config["hidden"], depth=config["mlp_depth"],
+        public_size=config["public_size"], public_per_round=config["public_per_round"],
+        private_size=config["private_size"], local_steps=config["local_steps"],
+        distill_steps=config["distill_steps"], lr=config["lr"], lr_dist=config["lr_dist"],
+        cluster_scale=config["cluster_scale"], noise=config["noise"],
+        beta=config["beta"], cache_duration=config["cache_duration"],
+        quant_bits=int(codec[len("cache_delta+quant"):]),
+        index_bytes=config["index_bytes"],
+        participants=cost.participants(config, traffic),
+        eval_every=traffic["eval_every"])

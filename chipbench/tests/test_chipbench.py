@@ -1,0 +1,312 @@
+"""CPU tests of the benchmark: trace reduction, cost counts, loading by
+name, the refusal without a chip, and the correctness comparison (the
+program agrees with the plain reference, the bfloat16 control and the
+planted faults do not).
+
+    PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest chipbench/tests
+"""
+import json
+import os
+import shutil
+import subprocess
+import sys
+import time
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from chipbench import compare, cost, spec
+from chipbench import trace as tr
+
+REPO = spec.REPO
+
+
+# -- trace reduction -----------------------------------------------------
+
+def _op(name, opcode, start, end):
+    return (f"%{name} = f32[8,128]{{1,0}} {opcode}(f32[8,128]{{1,0}} %x)", start, end)
+
+
+def _trace():
+    # device 0: two computations overlapping, a kernel, an all-reduce half
+    # hidden behind compute; device 1: one op.  Window 0..100 ns.
+    dev0 = [_op("fusion.1", "fusion", 0, 20), _op("fusion.2", "fusion", 10, 30),
+            _op("fused_round.3", "custom-call", 40, 50),
+            _op("all-reduce.4", "all-reduce", 45, 70)]
+    dev1 = [_op("fusion.1", "fusion", 0, 60)]
+    spans = [("call", 0, 55), ("call", 60, 100)]
+    return tr.Trace({"/device:TPU:0": dev0, "/device:TPU:1": dev1}, spans, (0, 100))
+
+
+def test_busy_union_and_idle_share():
+    t = _trace()
+    assert tr.union([(0, 20), (10, 30), (40, 50), (45, 70)]) == [(0, 30), (40, 70)]
+    # device 0 busy 60 ns, device 1 busy 60 ns, of 100
+    assert tr.busy_s(t) == pytest.approx(60e-9)
+    assert tr.idle_share(t) == pytest.approx(0.4)
+
+
+def test_kernel_time_by_instruction_name():
+    t = _trace()
+    secs, n = tr.op_time_s(t, lambda text: tr.op_name(text).startswith("fused_round"))
+    assert (secs, n) == (pytest.approx(10e-9), 1)
+    assert tr.opcode(t.devices["/device:TPU:0"][2][0]) == "custom-call"
+
+
+def test_interval_subtraction_and_clipping():
+    assert tr.subtract([(0, 100)], [(10, 30), (90, 120)]) == [(0, 10), (30, 90)]
+    assert tr.clip([(-5, 5), (50, 60), (95, 130)], (0, 100)) == [(0, 5), (50, 60), (95, 100)]
+    assert tr.length([(0, 10), (30, 90)]) == 70
+
+
+def test_idle_gaps_named_by_host_span():
+    gaps = tr.idle_gaps(_trace())
+    assert gaps[0] == ["call", pytest.approx(30e-9)]       # 70..100, inside a call
+    assert ["call", pytest.approx(10e-9)] in gaps          # 30..40
+    top = tr.top_ops(_trace(), 2)
+    assert top[0][0] == "fusion.1 fusion"
+
+
+def test_metric_readers_on_a_synthesized_trace():
+    cell = spec.load_cell("mnist-iid-scan-full")
+    rec = {"trace": _trace(), "config": cell.config, "traffic": cell.traffic,
+           "calls": 1, "rounds": 20, "chips": 1, "peak": spec.peaks("TPU v5 lite")}
+    assert spec.metric_reader("idle_share")(rec) == pytest.approx(40.0)
+    need = cost.fused_round_cost(cell.config, cell.traffic)
+    least = need["bytes"] / 819e9 * 20
+    assert spec.metric_reader("roofline.fused_round")(rec) == pytest.approx(100 * least / 10e-9)
+    rec["trace"] = tr.Trace({"/device:TPU:0": [_op("f", "fusion", 0, 5)]}, [], (0, 10))
+    assert spec.metric_reader("roofline.fused_round")(rec) is None
+
+
+# -- cost, by hand ---------------------------------------------------------
+
+def test_cost_of_the_2nn_by_hand():
+    c = spec.load_cell("mnist-iid-scan-full").config
+    dims = cost.mlp_dims(c)
+    assert dims == [784, 200, 200, 10]
+    assert cost.param_count(dims) == 199_210            # FedAvg's MNIST 2NN
+    macs = 784 * 200 + 200 * 200 + 200 * 10             # 198,800
+    assert cost.forward_flops(dims) == 2 * macs
+    # forward + weight gradients + input gradients of layers 2 and 3
+    assert cost.train_step_flops(dims) == 2 * macs + 2 * macs + 2 * (200 * 200 + 200 * 10)
+
+
+def _files(config: str, traffic: str):
+    """A configuration and a traffic mix by file name, benchmarked or not."""
+    c = json.loads((REPO / "chipbench/configs" / f"{config}.json").read_text())
+    t = json.loads((REPO / "chipbench/traffic" / f"{traffic}.json").read_text())
+    return c, t
+
+
+def _c10():
+    """The configuration under FedAvg's C=0.1: 10 of 100 clients a round."""
+    c, t = _files("fedavg-mnist-iid-2nn", "scan-all")
+    return c, dict(t, participants=10)
+
+
+def test_round_cost_of_both_configurations_by_hand():
+    full = spec.load_cell("mnist-iid-scan-full")
+    step, fwd = 879_200, 397_600
+    # 100 clients x 5 local steps x 450 train rows (90% of 500), 5 distill
+    # steps on 1,000 rows, 1,000 predictions; the server's 5 distill steps
+    want = 100 * 5 * 450 * step + 100 * 5 * 1000 * step + 100 * 1000 * fwd + 5 * 1000 * step
+    assert cost.round_flops(full.config, full.traffic, eval_round=False) == want
+    # eval: server and client accuracy on 10,000 test rows, 5,000 validation
+    # rows, 100 clients and the server on the 1,000-row public validation split
+    ev = (10_000 + 10_000 + 5_000 + 100 * 1000 + 1000) * fwd
+    assert cost.round_flops(full.config, full.traffic, eval_round=True) == want + ev
+    assert cost.call_flops(full.config, full.traffic) == 18 * want + 2 * (want + ev)
+
+    c10_config, c10_traffic = _c10()
+    want10 = 10 * 5 * 450 * step + 10 * 5 * 1000 * step + 10 * 1000 * fwd + 5 * 1000 * step
+    assert cost.round_flops(c10_config, c10_traffic, eval_round=False) == want10
+
+    # a cross-device population of LEAF FEMNIST's shape (arXiv 1812.01097):
+    # 805,263 samples over 3,548 clients, 62 classes, 100 clients a round
+    fem_config = dict(c10_config, n_clients=3548, n_classes=62, private_size=805_263)
+    fem_traffic = dict(c10_traffic, participants=100)
+    dims = cost.mlp_dims(fem_config)
+    assert cost.param_count(dims) == 209_662
+    rows = cost.uniform_rows(805_263, 3548)
+    assert rows.sum() == 805_263 and set(rows) == {226, 227}
+    assert set(cost.train_rows(805_263, 3548)) == {203, 204}   # int(0.9 n)
+    fstep = 2 * (156_800 + 40_000 + 12_400) * 2 + 2 * (40_000 + 12_400)
+    got = cost.round_flops(fem_config, fem_traffic, eval_round=False)
+    mean_train = cost.train_rows(805_263, 3548).mean()
+    want_f = (100 * 5 * mean_train * fstep + 100 * 5 * 1000 * fstep
+              + 100 * 1000 * 2 * 209_200 + 5 * 1000 * fstep)
+    assert got == pytest.approx(want_f, rel=1e-12)
+
+
+def test_fused_round_bytes_count_the_unpadded_stack():
+    need = cost.fused_round_cost(*_c10())
+    # 10 participants x 1,000 rows x 10 classes, their weights, base and output
+    assert need["bytes"] == 4 * (10 * 1000 * 10 + 10 + 2 * 1000 * 10)
+
+
+# -- loading by name -------------------------------------------------------
+
+def test_every_config_mix_metric_and_cell_loads_by_name():
+    bench = spec.load_benchmark()
+    for w in bench["workloads"]:
+        cell = spec.load_cell(w["name"])
+        assert cell.chips == w["chips"]
+        assert set(cell.limits) == set(compare.NAMES)
+        assert {m["name"] for m in cell.end_to_end} == {"round_ms", "peak_hbm_gb", "setup_s"}
+        for m in cell.per_layer:
+            assert callable(spec.metric_reader(m["name"]))
+        assert cell.config["name"] == w["config"]
+    for c in bench["configs"]:
+        assert set(c["reduced"]) <= set(json.loads((REPO / c["file"]).read_text()))
+    with pytest.raises(KeyError):
+        spec.peaks("TPU v99")
+
+
+def _copy_bench(dst: Path) -> Path:
+    shutil.copy(REPO / "BENCHMARK.json", dst / "BENCHMARK.json")
+    shutil.copytree(REPO / "chipbench", dst / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    return dst
+
+
+def test_a_cell_from_new_files_only(tmp_path):
+    """A later PR adds a configuration, a mix, limits, a metric reader and
+    a cell by adding files and entries; no file that exists changes."""
+    root = _copy_bench(tmp_path)
+    before = {p: p.read_bytes() for p in (root / "chipbench").rglob("*") if p.is_file()}
+    cfg = json.loads((root / "chipbench/configs/fedavg-mnist-iid-2nn.json").read_text())
+    cfg.update(name="new-model", n_clients=50)
+    (root / "chipbench/configs/new-model.json").write_text(json.dumps(cfg))
+    (root / "chipbench/traffic/new-mix.json").write_text(json.dumps(
+        {"engine": "scan", "participants": 5, "rounds_per_call": 10, "eval_every": 10,
+         "fused_round": False}))
+    (root / "chipbench/limits/new-cell.json").write_text(json.dumps(
+        dict.fromkeys(compare.NAMES, 0.0)))
+    (root / "chipbench/metrics/new_metric.py").write_text("def read(rec):\n    return 1.0\n")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "new-model", "source": "x", "reduced": [], "why": "x",
+                             "file": "chipbench/configs/new-model.json"})
+    bench["workloads"].append({"name": "new-cell", "config": "new-model",
+                               "traffic": "new-mix", "chips": 1, "why": "x"})
+    bench["per_layer"].append({"name": "new_metric", "unit": "%", "better": "higher",
+                               "source": "device_trace", "layer": "device",
+                               "moves": "round_ms", "workloads": ["new-cell"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    cell = spec.load_cell("new-cell", root)
+    assert cell.config["n_clients"] == 50 and cell.traffic["participants"] == 5
+    assert [m["name"] for m in cell.per_layer] == ["idle_share", "mfu.round", "new_metric"]
+    assert spec.metric_reader("new_metric", root)({}) == 1.0
+    assert cost.participants(cell.config, cell.traffic) == 5
+    for p, data in before.items():
+        assert p.read_bytes() == data, p
+
+
+# -- no chip ---------------------------------------------------------------
+
+def test_no_tpu_exits_non_zero_without_a_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO / "src"))
+    p = subprocess.run([sys.executable, "-m", "chipbench.run", "--workload",
+                        "mnist-iid-scan-full", "--seed", "2147483701", "--seconds", "1"],
+                       cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "TPU" in p.stderr
+
+
+# -- correctness: program, control, faults -----------------------------------
+
+TINY = dict(n_clients=8, private_size=2400, public_size=1000, public_per_round=100)
+
+
+def _tiny_cell(name: str, **traffic) -> spec.Cell:
+    """The named cell at a size the CPU holds: its widths, codec and limits,
+    with a small population and few rounds per call."""
+    cell = spec.load_cell(name)
+    cell.config = dict(cell.config, **TINY)
+    cell.traffic = dict(cell.traffic, rounds_per_call=4, eval_every=2, **traffic)
+    if cell.traffic["participants"] != "all":
+        cell.traffic["participants"] = 3
+    cell.chips = 1
+    return cell
+
+
+def _run(cell, hook=None):
+    from chipbench import run
+    out, lines = run.measure(cell, 2_147_483_999, 0.2, False, t_start=time.perf_counter(),
+                             require_chip=False, engine_hook=hook)
+    return out
+
+
+CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_program_matches_the_reference(name):
+    out = _run(_tiny_cell(name))
+    assert out["correct"], out["checks"]
+    assert out["checks"]["ledger"]["value"] == 0.0
+    assert out["checks"]["cache_state"]["value"] == 0.0
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_bfloat16_control_fails_the_limits(name):
+    from chipbench import calibrate, run
+    cell = _tiny_cell(name)
+    ref = run.reference_result(cell, 5)
+    ctl = run.reference_result(cell, 5, dtype="bfloat16")
+    ok, table, _ = compare.judge(compare.readings(calibrate._ref_as_record(ctl), ref),
+                                 cell.limits)
+    assert not ok, table
+
+
+def _state_unchanged(engine):
+    """Every round hands back the state it was given: the scan body
+    returns its carry."""
+    inner = engine._round_device
+
+    def body(carry, xs):
+        _, ys = inner(carry, xs)
+        return carry, ys
+    engine._round_device = body
+
+
+def _half_batch(engine):
+    """Aggregation over the first half of the participants only."""
+    s = engine.strategy
+
+    def halve(part):
+        k = part.shape[0]
+        return part * (np.arange(k) < k // 2)
+    fused, plain = s.aggregate_masked_fused, s.aggregate_masked
+    s.aggregate_masked_fused = lambda z, part, spec_, base, t: fused(z, halve(part), spec_,
+                                                                     base, t)
+    s.aggregate_masked = lambda z, part, um, t: plain(z, halve(part), um, t)
+
+
+def _answer_altered(engine):
+    """The first round's uplink charged for one participant fewer, where
+    the ledger is made."""
+    from repro.core import comm
+    run_ = engine.run
+    n = max(round(engine.cfg.participation * engine.cfg.n_clients), 1)
+
+    def run(rounds=None):
+        hist = run_(rounds)
+        first = hist.ledger.rounds[0]
+        hist.ledger.rounds[0] = comm.RoundCost(first.uplink * (n - 1) / n, first.downlink)
+        return hist
+    engine.run = run
+
+
+FAULTS = {"state_unchanged": _state_unchanged, "half_batch": _half_batch,
+          "answer_altered": _answer_altered}
+
+
+@pytest.mark.parametrize("name,fault", [(n, f) for n in CELLS for f in FAULTS])
+def test_planted_fault_is_not_correct(name, fault):
+    out = _run(_tiny_cell(name), FAULTS[fault])
+    assert not out["correct"], out["checks"]
+    if fault == "state_unchanged":
+        assert out["checks"]["client_step"]["value"] == pytest.approx(1.0)
