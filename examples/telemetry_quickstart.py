@@ -2,7 +2,7 @@
 
 Device plane: ``telemetry=True`` threads a ``RoundTelemetry`` pytree
 through the round body — participation, cache hit/miss/expiry, catch-up
-and wire bytes, teacher-entropy/beta/codec-error gauges — accumulated
+and wire bytes, teacher-entropy/beta/codec-error gauges — computed
 on device (inside the single-compilation ``lax.scan`` on the scanned
 engine: no host callbacks) and returned as ``History.telemetry``.
 

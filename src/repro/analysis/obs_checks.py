@@ -1,7 +1,7 @@
 """Pass 4: telemetry-plane contracts for the scanned engines.
 
-``FLConfig.telemetry`` threads a ``RoundTelemetry`` pytree through the
-``lax.scan`` round body.  Two things must stay true, and neither is
+``FLConfig.telemetry`` adds a ``RoundTelemetry`` row to the outputs of
+the ``lax.scan`` round body.  Two things must stay true, and neither is
 checked anywhere at runtime:
 
 1. **Scan safety**: the instrumented round body (including any
@@ -12,11 +12,12 @@ checked anywhere at runtime:
    enabled round body of representative engine variants on abstract
    shapes and fails on any :data:`~repro.analysis.traceutil.
    CALLBACK_PRIMITIVES` hit.
-2. **Off-path inertness**: with telemetry off, the round body's carry
-   and ``ys`` trees must not mention telemetry at all, and the
-   telemetry-on trees must differ from the off trees by EXACTLY the
-   ``telemetry`` entry — the structural form of the "off path leaves
-   golden ledgers byte-identical" guarantee.
+2. **Off-path inertness**: the round body's carry tree must be the
+   same with telemetry on and off (telemetry is an output, never
+   state), the telemetry-off ``ys`` tree must not mention telemetry,
+   and the telemetry-on ``ys`` tree must differ from the off tree by
+   EXACTLY the ``telemetry`` entry — the structural form of the "off
+   path leaves golden ledgers byte-identical" guarantee.
 
 Everything is trace-only (``jax.make_jaxpr`` / ``jax.eval_shape`` on
 ``ShapeDtypeStruct``): no training runs.
@@ -81,8 +82,10 @@ def check_round_body(subject: str, eng) -> List[Finding]:
 
 
 def check_off_on_structure(subject: str, make) -> List[Finding]:
-    """Telemetry must be structurally additive: off-trees contain no
-    telemetry entry, on-trees differ from off by exactly that entry."""
+    """Telemetry must be structurally additive: the carry trees are
+    identical with telemetry on and off, the off ``ys`` tree contains
+    no telemetry entry, and the on ``ys`` tree differs from the off one
+    by exactly that entry."""
     import jax
 
     findings: List[Finding] = []
@@ -92,33 +95,40 @@ def check_off_on_structure(subject: str, make) -> List[Finding]:
         carry, xs = _round_abstract(eng)
         out_carry, ys = jax.eval_shape(
             lambda c, x: eng._round_device(c, x), carry, xs)
-        shapes[tel] = (dict(out_carry), dict(ys))
-    for tree_name, i in (("carry", 0), ("ys", 1)):
+        shapes[tel] = (carry, out_carry, dict(ys))
+    for i, tree_name in ((0, "carry in"), (1, "carry out")):
         off, on = shapes[False][i], shapes[True][i]
-        if "telemetry" in off:
+        if (jax.tree_util.tree_structure(off)
+                != jax.tree_util.tree_structure(on)
+                or jax.tree_util.tree_leaves(off)
+                != jax.tree_util.tree_leaves(on)):
             findings.append(Finding(
                 "error", "obs", subject,
-                f"telemetry-OFF round body emits a telemetry entry in "
-                f"{tree_name} — the off path must be untouched"))
-        if "telemetry" not in on:
-            findings.append(Finding(
-                "error", "obs", subject,
-                f"telemetry-ON round body missing the telemetry entry "
-                f"in {tree_name}"))
-        off_rest = {k: v for k, v in off.items()}
-        on_rest = {k: v for k, v in on.items() if k != "telemetry"}
-        if off_rest != on_rest:
-            findings.append(Finding(
-                "error", "obs", subject,
-                f"telemetry changes the {tree_name} structure beyond its "
-                f"own entry (off={sorted(off_rest)}, "
-                f"on-minus-telemetry={sorted(on_rest)}) — the off-path "
-                "byte-identity guarantee is at risk"))
+                f"telemetry changes the {tree_name} tree "
+                f"(off={sorted(off)}, on={sorted(on)}) — telemetry must "
+                "leave the round's state untouched"))
+    off, on = shapes[False][2], shapes[True][2]
+    if "telemetry" in off:
+        findings.append(Finding(
+            "error", "obs", subject,
+            "telemetry-OFF round body emits a telemetry entry in ys — "
+            "the off path must be untouched"))
+    if "telemetry" not in on:
+        findings.append(Finding(
+            "error", "obs", subject,
+            "telemetry-ON round body missing the telemetry entry in ys"))
+    on_rest = {k: v for k, v in on.items() if k != "telemetry"}
+    if off != on_rest:
+        findings.append(Finding(
+            "error", "obs", subject,
+            f"telemetry changes the ys structure beyond its own entry "
+            f"(off={sorted(off)}, on-minus-telemetry={sorted(on_rest)}) "
+            "— the off-path byte-identity guarantee is at risk"))
     if not findings:
         findings.append(Finding(
             "ok", "obs", subject,
-            "telemetry is structurally additive (off trees untouched; "
-            "on trees differ by exactly the telemetry entry)"))
+            "telemetry is structurally additive (carry trees identical; "
+            "ys trees differ by exactly the telemetry entry)"))
     return findings
 
 

@@ -232,10 +232,10 @@ def check_engine(mesh: str = "2x4", n_clients: int = 8) -> List[Finding]:
     from repro.fl.strategies import STRATEGIES
 
     findings: List[Finding] = []
-    # telemetry=True variants prove the RoundTelemetry carry leaves —
-    # declared replicated (P()) like last_sync — really are shard-
-    # invariant: counters from the replicated full-width draw, gauges
-    # psum'd over the client axis before entering the row
+    # telemetry=True variants prove the RoundTelemetry rows — declared
+    # replicated (P()) like the other per-round outputs — really are
+    # shard-invariant: counters from the replicated full-width draw,
+    # gauges psum'd over the client axis before entering the row
     for name in ("scarlet", "mean"):
         for telemetry in (False, True):
             cfg = FLConfig(n_clients=n_clients, rounds=1, public_size=32,

@@ -105,7 +105,7 @@ def run_method(
     ``History.telemetry`` holds one
     :class:`~repro.obs.device.RoundTelemetry` row per round (cache
     hit/miss census, staleness, payload bytes, entropy/beta gauges),
-    accumulated inside the compiled round body on every engine.  The
+    computed inside the compiled round body on every engine.  The
     baselines reject it — there is no distillation round to instrument.
     """
     if engine not in _ENGINES:

@@ -73,6 +73,7 @@ from repro.core import cache as cache_lib
 from repro.core import comm as comm_lib
 from repro.kernels import round_kernel
 from repro.obs import device as obs_device
+from repro.obs.trace import stage
 from repro.fl.scan_engine import ScannedFederatedDistillation
 from repro.fl.strategies.base import TRANSMIT_SALT
 from repro.fl.rounds import (
@@ -122,29 +123,32 @@ class AsyncFederatedDistillation(ScannedFederatedDistillation):
         t, offline_t, do_eval, avail_t, delay_t = xs
 
         # same per-round key stream as scan/host (fold_in by absolute t)
-        kt = jax.random.fold_in(self._key_rounds, t)
-        k_idx, k_part = jax.random.split(kt)
-        idx = jnp.sort(jax.random.choice(
-            k_idx, c.public_size, (c.public_per_round,), replace=False))
+        with stage("cache"):
+            kt = jax.random.fold_in(self._key_rounds, t)
+            k_idx, k_part = jax.random.split(kt)
+            idx = jnp.sort(jax.random.choice(
+                k_idx, c.public_size, (c.public_per_round,), replace=False))
 
-        # --- dispatch: scan's participation draw with unreachable and
-        # in-flight clients folded into the offline mask (conscription
-        # then only recruits clients that could actually start work) ----
-        busy = carry["in_flight"]
-        blocked = jnp.logical_or(
-            offline_t, jnp.logical_or(jnp.logical_not(avail_t), busy))
-        dispatch = self.scenario.participation_mask_device(k_part, blocked)
-        disp_f = dispatch.astype(jnp.float32)
-        any_disp = jnp.sum(disp_f) > 0
+            # --- dispatch: scan's participation draw with unreachable
+            # and in-flight clients folded into the offline mask
+            # (conscription then only recruits clients that could
+            # actually start work) ---------------------------------------
+            busy = carry["in_flight"]
+            blocked = jnp.logical_or(
+                offline_t, jnp.logical_or(jnp.logical_not(avail_t), busy))
+            dispatch = self.scenario.participation_mask_device(k_part,
+                                                               blocked)
+            disp_f = dispatch.astype(jnp.float32)
+            any_disp = jnp.sum(disp_f) > 0
 
-        # --- arrivals: in-flight reports landing now + zero-delay
-        # dispatches (which complete inside their own window) ------------
-        arrive = jnp.logical_or(
-            jnp.logical_and(busy, carry["flight_arrival"] == t),
-            jnp.logical_and(dispatch, delay_t == 0))
-        arrive_f = arrive.astype(jnp.float32)
-        n_arr = jnp.sum(arrive_f)
-        any_arr = n_arr > 0
+            # --- arrivals: in-flight reports landing now + zero-delay
+            # dispatches (which complete inside their own window) --------
+            arrive = jnp.logical_or(
+                jnp.logical_and(busy, carry["flight_arrival"] == t),
+                jnp.logical_and(dispatch, delay_t == 0))
+            arrive_f = arrive.astype(jnp.float32)
+            n_arr = jnp.sum(arrive_f)
+            any_arr = n_arr > 0
 
         def gate(new, old):
             """Keep ``old`` wholesale on arrival-free rounds."""
@@ -156,118 +160,132 @@ class AsyncFederatedDistillation(ScannedFederatedDistillation):
         # (an in-flight client is never dispatched, so its report is
         # evaluated from dispatch-time parameters) -----------------------
         cp = carry["client_params"]
-        x_prev = self.x_pub[carry["prev_idx"]]
-        upd = self._distill_all(cp, x_prev, carry["prev_teacher"])
-        cp = _select_cohorts(upd, cp, self.models.split(
-            jnp.logical_and(dispatch, carry["have_prev"])))
-        upd = self._local_train_all(cp, t)
-        cp = _select_cohorts(upd, cp, self.models.split(dispatch))
+        with stage("client_distill"):
+            x_prev = self.x_pub[carry["prev_idx"]]
+            upd = self._distill_all(cp, x_prev, carry["prev_teacher"])
+            cp = _select_cohorts(upd, cp, self.models.split(
+                jnp.logical_and(dispatch, carry["have_prev"])))
+        with stage("local_train"):
+            upd = self._local_train_all(cp, t)
+            cp = _select_cohorts(upd, cp, self.models.split(dispatch))
 
         # --- request list at the ARRIVAL round's subset ------------------
         cache_prev = carry["cache"]
-        if self.use_cache:
-            key_exp = (jax.random.fold_in(jax.random.PRNGKey(c.seed), t)
-                       if self.probabilistic_expiry else None)
-            miss = cache_lib.miss_mask(cache_prev, idx, t, self.D,
-                                       probabilistic=self.probabilistic_expiry,
-                                       key=key_exp)
-        else:
-            miss = jnp.ones(c.public_per_round, bool)
-        miss_f = miss.astype(jnp.float32)
-        n_req = jnp.sum(miss_f)
-        base, base_present = cache_lib.cached_at(cache_prev, idx)
+        with stage("cache"):
+            if self.use_cache:
+                key_exp = (jax.random.fold_in(jax.random.PRNGKey(c.seed), t)
+                           if self.probabilistic_expiry else None)
+                miss = cache_lib.miss_mask(
+                    cache_prev, idx, t, self.D,
+                    probabilistic=self.probabilistic_expiry, key=key_exp)
+            else:
+                miss = jnp.ones(c.public_per_round, bool)
+            miss_f = miss.astype(jnp.float32)
+            n_req = jnp.sum(miss_f)
+            base, base_present = cache_lib.cached_at(cache_prev, idx)
+
+            # dispatch-updated sync points: staleness of an arrival is
+            # the number of rounds its report spent in flight
+            ls_mid = jnp.where(dispatch, t - 1, carry["last_sync"])
 
         # --- staleness-weighted aggregation over ARRIVALS ----------------
-        # dispatch-updated sync points: staleness of an arrival is the
-        # number of rounds its report spent in flight
-        ls_mid = jnp.where(dispatch, t - 1, carry["last_sync"])
-        x_round = self.x_pub[idx]
-        z_all = self._predict_all(cp, x_round)
-        z_all = s.transmit(z_all, jax.random.fold_in(kt, TRANSMIT_SALT))
-        z_tx = z_all
-        if self._unit_staleness:
-            w = arrive_f
-        else:
-            w = arrive_f * s.staleness_weight(t - 1 - ls_mid)
-        if self._fused:
-            um = s.upload_mask(z_all)
-            fbase = (round_kernel.resolve_delta_base(
-                         base, base_present, c.public_per_round, c.n_classes)
-                     if self._fused_spec["mode"] == "delta" else None)
-            fresh = s.aggregate_masked_fused(z_all, w, self._fused_spec,
-                                             fbase, t)
-        else:
-            if not self.codec_up.is_identity:
-                z_all = self.codec_up.roundtrip(z_all, base=base,
-                                                present=base_present)
-            um = s.upload_mask(z_all)
-            fresh = s.aggregate_masked(z_all, w, um, t)
-        if not self.codec_down.is_identity:
-            fresh = self.codec_down.roundtrip(fresh, base=base,
-                                              present=base_present)
+        with stage("predict"):
+            x_round = self.x_pub[idx]
+            z_all = self._predict_all(cp, x_round)
+        with stage("aggregate"):
+            z_all = s.transmit(z_all, jax.random.fold_in(kt, TRANSMIT_SALT))
+            z_tx = z_all
+            if self._unit_staleness:
+                w = arrive_f
+            else:
+                w = arrive_f * s.staleness_weight(t - 1 - ls_mid)
+            if self._fused:
+                um = s.upload_mask(z_all)
+                fbase = (round_kernel.resolve_delta_base(
+                             base, base_present, c.public_per_round,
+                             c.n_classes)
+                         if self._fused_spec["mode"] == "delta" else None)
+                fresh = s.aggregate_masked_fused(z_all, w, self._fused_spec,
+                                                 fbase, t)
+            else:
+                if not self.codec_up.is_identity:
+                    z_all = self.codec_up.roundtrip(z_all, base=base,
+                                                    present=base_present)
+                um = s.upload_mask(z_all)
+                fresh = s.aggregate_masked(z_all, w, um, t)
+            if not self.codec_down.is_identity:
+                fresh = self.codec_down.roundtrip(fresh, base=base,
+                                                  present=base_present)
 
         # --- teacher + cache + server updates, gated on arrivals ---------
-        cache = cache_prev
-        if self.use_cache:
-            teacher = cache_lib.assemble_teacher(cache_prev, idx, fresh, miss)
-            new_cache, _ = cache_lib.update_global_cache(
-                cache_prev, idx, teacher, miss, t)
-            cache = gate(new_cache, cache_prev)
-        else:
-            teacher = fresh
+        with stage("cache"):
+            cache = cache_prev
+            if self.use_cache:
+                teacher = cache_lib.assemble_teacher(cache_prev, idx, fresh,
+                                                     miss)
+                new_cache, _ = cache_lib.update_global_cache(
+                    cache_prev, idx, teacher, miss, t)
+                cache = gate(new_cache, cache_prev)
+            else:
+                teacher = fresh
 
-        sp = distill(carry["server_params"], x_round, teacher,
-                     c.lr_dist, c.distill_steps)
-        server_params = gate(sp, carry["server_params"])
-        zv = self._predict_all(cp, self.x_pub[self.pub_val_idx])
-        teacher_val = jnp.where(any_arr, jnp.mean(zv, axis=0),
-                                carry["teacher_val"])
-        have_tv = jnp.logical_or(carry["have_tv"], any_arr)
-        prev_teacher = jnp.where(any_arr, teacher, carry["prev_teacher"])
-        prev_idx = jnp.where(any_arr, idx, carry["prev_idx"])
-        have_prev = jnp.logical_or(carry["have_prev"], any_arr)
+        with stage("server_distill"):
+            sp = distill(carry["server_params"], x_round, teacher,
+                         c.lr_dist, c.distill_steps)
+            server_params = gate(sp, carry["server_params"])
+        with stage("predict"):
+            zv = self._predict_all(cp, self.x_pub[self.pub_val_idx])
+            teacher_val = jnp.where(any_arr, jnp.mean(zv, axis=0),
+                                    carry["teacher_val"])
+            have_tv = jnp.logical_or(carry["have_tv"], any_arr)
+        with stage("cache"):
+            prev_teacher = jnp.where(any_arr, teacher,
+                                     carry["prev_teacher"])
+            prev_idx = jnp.where(any_arr, idx, carry["prev_idx"])
+            have_prev = jnp.logical_or(carry["have_prev"], any_arr)
 
-        # --- ledger: dispatch-time uplink, two-sided catch-up ------------
-        catch_up = jnp.float32(0.0)
-        catch_disp = jnp.float32(0.0)
-        if self.use_cache:
-            catch_up, catch_disp = cache_lib.catch_up_bytes_async(
-                cache_prev, carry["last_sync"], dispatch, arrive, t)
-        # per-arrival upload size is the request-list size of each
-        # client's DISPATCH round; the cost model takes the per-client
-        # average (exact n_req when everything arrives same-round)
-        flight_nreq = jnp.where(dispatch, n_req, carry["flight_nreq"])
-        n_up = jnp.sum(arrive_f * flight_nreq) / jnp.maximum(n_arr, 1.0)
-        if um is not None:  # Selective-FD gating, applied at arrival
-            uploaded_total = jnp.sum(
-                um.astype(jnp.float32) * arrive_f[:, None] * miss_f[None, :])
-            n_up = uploaded_total / jnp.maximum(n_arr, 1.0)
-        uplink, downlink = comm_lib.distillation_round_cost_device(
-            n_clients=n_arr,
-            n_selected=float(c.public_per_round),
-            n_up_samples=n_up,
-            n_down_samples=n_req,
-            n_classes=c.n_classes,
-            uplink_bits=s.uplink_bits,
-            downlink_bits=s.downlink_bits,
-            with_cache_signals=self.use_cache,
-            catch_up_down=catch_up,
-            bytes_index=c.index_bytes,
-            uplink_codec=self.codec_up,
-            downlink_codec=self.codec_down,
-        )
-        uplink = jnp.where(any_arr, uplink, 0.0)
-        # dispatch-side sync bytes flow even when nothing arrives
-        downlink = jnp.where(any_arr, downlink,
-                             jnp.where(any_disp, catch_disp, 0.0))
+            # --- ledger: dispatch-time uplink, two-sided catch-up -------
+            catch_up = jnp.float32(0.0)
+            catch_disp = jnp.float32(0.0)
+            if self.use_cache:
+                catch_up, catch_disp = cache_lib.catch_up_bytes_async(
+                    cache_prev, carry["last_sync"], dispatch, arrive, t)
+            # per-arrival upload size is the request-list size of each
+            # client's DISPATCH round; the cost model takes the per-client
+            # average (exact n_req when everything arrives same-round)
+            flight_nreq = jnp.where(dispatch, n_req, carry["flight_nreq"])
+            n_up = jnp.sum(arrive_f * flight_nreq) / jnp.maximum(n_arr, 1.0)
+            if um is not None:  # Selective-FD gating, applied at arrival
+                uploaded_total = jnp.sum(
+                    um.astype(jnp.float32) * arrive_f[:, None]
+                    * miss_f[None, :])
+                n_up = uploaded_total / jnp.maximum(n_arr, 1.0)
+            uplink, downlink = comm_lib.distillation_round_cost_device(
+                n_clients=n_arr,
+                n_selected=float(c.public_per_round),
+                n_up_samples=n_up,
+                n_down_samples=n_req,
+                n_classes=c.n_classes,
+                uplink_bits=s.uplink_bits,
+                downlink_bits=s.downlink_bits,
+                with_cache_signals=self.use_cache,
+                catch_up_down=catch_up,
+                bytes_index=c.index_bytes,
+                uplink_codec=self.codec_up,
+                downlink_codec=self.codec_down,
+            )
+            uplink = jnp.where(any_arr, uplink, 0.0)
+            # dispatch-side sync bytes flow even when nothing arrives
+            downlink = jnp.where(any_arr, downlink,
+                                 jnp.where(any_disp, catch_disp, 0.0))
 
-        # --- flight + sync bookkeeping -----------------------------------
-        last_sync = jnp.where(arrive, t, ls_mid)
-        in_flight = jnp.logical_or(
-            jnp.logical_and(busy, jnp.logical_not(arrive)),
-            jnp.logical_and(dispatch, delay_t > 0))
-        flight_arrival = jnp.where(dispatch, t + delay_t,
-                                   carry["flight_arrival"])
+            # --- flight + sync bookkeeping ------------------------------
+            last_sync = jnp.where(arrive, t, ls_mid)
+            in_flight = jnp.logical_or(
+                jnp.logical_and(busy, jnp.logical_not(arrive)),
+                jnp.logical_and(dispatch, delay_t > 0))
+            flight_arrival = jnp.where(dispatch, t + delay_t,
+                                       carry["flight_arrival"])
 
         # --- telemetry: arrivals are the participants; pre-round
         # last_sync makes staleness buckets equal report delay ------------
@@ -285,20 +303,21 @@ class AsyncFederatedDistillation(ScannedFederatedDistillation):
 
         # --- eval (scheduled rounds only) --------------------------------
         def _eval():
-            sa = accuracy(server_params, self.x_test, self.y_test,
-                          jnp.ones(len(self.y_test)))
-            accs = [accuracy_v(p, self.xts_c[i], self.yts_c[i],
-                               self.tmask_c[i].astype(jnp.float32))
-                    for i, p in enumerate(cp)]
-            ca = jnp.mean(self.models.concat(accs))
-            cacc = jnp.stack([jnp.mean(a) for a in accs])
-            sv = val_loss_soft(server_params, self.x_pub[self.pub_val_idx],
-                               teacher_val)
-            cv = jnp.mean(self.models.concat(
-                [val_loss_hard_v(p, self.xs_c[i], self.ys_c[i],
-                                 self.val_mask_c[i].astype(jnp.float32))
-                 for i, p in enumerate(cp)]))
-            return sa, ca, sv, cv, cacc
+            with stage("eval"):
+                sa = accuracy(server_params, self.x_test, self.y_test,
+                              jnp.ones(len(self.y_test)))
+                accs = [accuracy_v(p, self.xts_c[i], self.yts_c[i],
+                                   self.tmask_c[i].astype(jnp.float32))
+                        for i, p in enumerate(cp)]
+                ca = jnp.mean(self.models.concat(accs))
+                cacc = jnp.stack([jnp.mean(a) for a in accs])
+                sv = val_loss_soft(server_params,
+                                   self.x_pub[self.pub_val_idx], teacher_val)
+                cv = jnp.mean(self.models.concat(
+                    [val_loss_hard_v(p, self.xs_c[i], self.ys_c[i],
+                                     self.val_mask_c[i].astype(jnp.float32))
+                     for i, p in enumerate(cp)]))
+                return sa, ca, sv, cv, cacc
 
         sa, ca, sv, cv, cacc = jax.lax.cond(
             do_eval, _eval,
@@ -323,8 +342,6 @@ class AsyncFederatedDistillation(ScannedFederatedDistillation):
                   server_acc=sa, client_acc=ca, server_val=sv, client_val=cv,
                   cohort_acc=cacc, have_tv=have_tv)
         if tel is not None:
-            new_carry["telemetry"] = obs_device.accumulate(
-                carry["telemetry"], tel)
             ys["telemetry"] = tel
         return new_carry, ys
 

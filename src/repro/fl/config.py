@@ -64,11 +64,11 @@ class FLConfig:
     # loop over clients — the only partition that is tractable at the
     # active-set engine's K = 10^6 benchmark scale).
     partition: str = "dirichlet"
-    # opt-in device-plane telemetry (repro.obs): accumulate a
-    # RoundTelemetry pytree (cache hit/miss census, participation and
+    # opt-in device-plane telemetry (repro.obs): compute a
+    # RoundTelemetry row (cache hit/miss census, participation and
     # staleness counters, payload bytes, teacher-entropy/beta gauges)
-    # inside the round body of every engine.  Rides the lax.scan carry
-    # on the device engines, so the run stays one XLA program with no
-    # host callbacks.  Off (the default) leaves every engine's program
+    # inside the round body of every engine.  Leaves the lax.scan with
+    # its other per-round outputs on the device engines, so the run
+    # stays one XLA program with no host callbacks.  Off (the default) leaves every engine's program
     # and golden ledger byte-identical to a build without the feature.
     telemetry: bool = False

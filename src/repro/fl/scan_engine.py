@@ -39,6 +39,7 @@ from repro.core import cache as cache_lib
 from repro.core import comm as comm_lib
 from repro.kernels import round_kernel
 from repro.obs import device as obs_device
+from repro.obs.trace import span, stage
 from repro.fl.strategies.base import TRANSMIT_SALT
 from repro.fl.rounds import (
     FederatedDistillation,
@@ -120,14 +121,15 @@ class ScannedFederatedDistillation(FederatedDistillation):
         c, s = self.cfg, self.strategy
         t, offline_t, do_eval = xs
 
-        kt = jax.random.fold_in(self._key_rounds, t)
-        k_idx, k_part = jax.random.split(kt)
-        idx = jnp.sort(jax.random.choice(
-            k_idx, c.public_size, (c.public_per_round,), replace=False))
-        part = self.scenario.participation_mask_device(k_part, offline_t)
-        part_f = part.astype(jnp.float32)
-        n_part = jnp.sum(part_f)
-        any_p = n_part > 0
+        with stage("cache"):
+            kt = jax.random.fold_in(self._key_rounds, t)
+            k_idx, k_part = jax.random.split(kt)
+            idx = jnp.sort(jax.random.choice(
+                k_idx, c.public_size, (c.public_per_round,), replace=False))
+            part = self.scenario.participation_mask_device(k_part, offline_t)
+            part_f = part.astype(jnp.float32)
+            n_part = jnp.sum(part_f)
+            any_p = n_part > 0
 
         def gate(new, old):
             """Keep ``old`` wholesale on total-outage rounds."""
@@ -137,104 +139,117 @@ class ScannedFederatedDistillation(FederatedDistillation):
         # --- clients: distill on previous teacher, then local training ----
         cp = carry["client_params"]
         part_c = self.models.split(part)
-        x_prev = self.x_pub[carry["prev_idx"]]
-        upd = self._distill_all(cp, x_prev, carry["prev_teacher"])
-        cp = _select_cohorts(upd, cp, self.models.split(
-            jnp.logical_and(part, carry["have_prev"])))
-        upd = self._local_train_all(cp, t)
-        cp = _select_cohorts(upd, cp, part_c)
+        with stage("client_distill"):
+            x_prev = self.x_pub[carry["prev_idx"]]
+            upd = self._distill_all(cp, x_prev, carry["prev_teacher"])
+            cp = _select_cohorts(upd, cp, self.models.split(
+                jnp.logical_and(part, carry["have_prev"])))
+        with stage("local_train"):
+            upd = self._local_train_all(cp, t)
+            cp = _select_cohorts(upd, cp, part_c)
 
         # --- request list (cache) ----------------------------------------
         cache_prev = carry["cache"]
-        if self.use_cache:
-            key_exp = (jax.random.fold_in(jax.random.PRNGKey(c.seed), t)
-                       if self.probabilistic_expiry else None)
-            miss = cache_lib.miss_mask(cache_prev, idx, t, self.D,
-                                       probabilistic=self.probabilistic_expiry,
-                                       key=key_exp)
-        else:
-            miss = jnp.ones(c.public_per_round, bool)
-        miss_f = miss.astype(jnp.float32)
-        n_req = jnp.sum(miss_f)
-        # shared delta-coding base: the synchronized cache at P^t (pre-update)
-        base, base_present = cache_lib.cached_at(cache_prev, idx)
+        with stage("cache"):
+            if self.use_cache:
+                key_exp = (jax.random.fold_in(jax.random.PRNGKey(c.seed), t)
+                           if self.probabilistic_expiry else None)
+                miss = cache_lib.miss_mask(
+                    cache_prev, idx, t, self.D,
+                    probabilistic=self.probabilistic_expiry, key=key_exp)
+            else:
+                miss = jnp.ones(c.public_per_round, bool)
+            miss_f = miss.astype(jnp.float32)
+            n_req = jnp.sum(miss_f)
+            # shared delta-coding base: the synchronized cache at P^t
+            # (pre-update)
+            base, base_present = cache_lib.cached_at(cache_prev, idx)
 
         # --- uplink + aggregation (fixed shapes, participation-masked) ----
-        x_round = self.x_pub[idx]
-        z_all = self._predict_all(cp, x_round)             # (K, m, N)
-        # per-round transmit key: an extra fold off kt (DCE'd when the
-        # strategy ignores it, so the legacy key stream is untouched)
-        z_all = s.transmit(z_all, jax.random.fold_in(kt, TRANSMIT_SALT))
-        z_tx = z_all  # as transmitted: telemetry's codec-error reference
-        if self._fused:
-            # fused fast path: uplink codec round trip + masked
-            # aggregation + sharpening in one round_kernel VMEM pass
-            um = s.upload_mask(z_all)
-            fbase = (round_kernel.resolve_delta_base(
-                         base, base_present, c.public_per_round, c.n_classes)
-                     if self._fused_spec["mode"] == "delta" else None)
-            fresh = s.aggregate_masked_fused(z_all, part_f,
-                                             self._fused_spec, fbase, t)
-        else:
-            if not self.codec_up.is_identity:  # lossy wire: server's view
-                z_all = self.codec_up.roundtrip(z_all, base=base,
-                                                present=base_present)
-            um = s.upload_mask(z_all)
-            fresh = s.aggregate_masked(z_all, part_f, um, t)
-        if not self.codec_down.is_identity:  # decoded broadcast (see rounds.py)
-            fresh = self.codec_down.roundtrip(fresh, base=base,
-                                              present=base_present)
+        with stage("predict"):
+            x_round = self.x_pub[idx]
+            z_all = self._predict_all(cp, x_round)         # (K, m, N)
+        with stage("aggregate"):
+            # per-round transmit key: an extra fold off kt (DCE'd when the
+            # strategy ignores it, so the legacy key stream is untouched)
+            z_all = s.transmit(z_all, jax.random.fold_in(kt, TRANSMIT_SALT))
+            z_tx = z_all  # as transmitted: telemetry's codec-error reference
+            if self._fused:
+                # fused fast path: uplink codec round trip + masked
+                # aggregation + sharpening in one round_kernel VMEM pass
+                um = s.upload_mask(z_all)
+                fbase = (round_kernel.resolve_delta_base(
+                             base, base_present, c.public_per_round,
+                             c.n_classes)
+                         if self._fused_spec["mode"] == "delta" else None)
+                fresh = s.aggregate_masked_fused(z_all, part_f,
+                                                 self._fused_spec, fbase, t)
+            else:
+                if not self.codec_up.is_identity:  # lossy wire: server's view
+                    z_all = self.codec_up.roundtrip(z_all, base=base,
+                                                    present=base_present)
+                um = s.upload_mask(z_all)
+                fresh = s.aggregate_masked(z_all, part_f, um, t)
+            if not self.codec_down.is_identity:  # decoded broadcast
+                fresh = self.codec_down.roundtrip(fresh, base=base,
+                                                  present=base_present)
 
         # --- assemble teacher + cache update ------------------------------
         cache = cache_prev
-        if self.use_cache:
-            teacher = cache_lib.assemble_teacher(cache_prev, idx, fresh, miss)
-            new_cache, _ = cache_lib.update_global_cache(
-                cache_prev, idx, teacher, miss, t)
-            cache = gate(new_cache, cache_prev)
-        else:
-            teacher = fresh
+        with stage("cache"):
+            if self.use_cache:
+                teacher = cache_lib.assemble_teacher(cache_prev, idx, fresh,
+                                                     miss)
+                new_cache, _ = cache_lib.update_global_cache(
+                    cache_prev, idx, teacher, miss, t)
+                cache = gate(new_cache, cache_prev)
+            else:
+                teacher = fresh
 
         # --- server distillation + App.-D proxy teacher -------------------
-        sp = distill(carry["server_params"], x_round, teacher,
-                     c.lr_dist, c.distill_steps)
-        server_params = gate(sp, carry["server_params"])
-        zv = self._predict_all(cp, self.x_pub[self.pub_val_idx])
-        teacher_val = jnp.where(any_p, jnp.mean(zv, axis=0),
-                                carry["teacher_val"])
-        have_tv = jnp.logical_or(carry["have_tv"], any_p)
+        with stage("server_distill"):
+            sp = distill(carry["server_params"], x_round, teacher,
+                         c.lr_dist, c.distill_steps)
+            server_params = gate(sp, carry["server_params"])
+        with stage("predict"):
+            zv = self._predict_all(cp, self.x_pub[self.pub_val_idx])
+            teacher_val = jnp.where(any_p, jnp.mean(zv, axis=0),
+                                    carry["teacher_val"])
+            have_tv = jnp.logical_or(carry["have_tv"], any_p)
 
-        prev_teacher = jnp.where(any_p, teacher, carry["prev_teacher"])
-        prev_idx = jnp.where(any_p, idx, carry["prev_idx"])
-        have_prev = jnp.logical_or(carry["have_prev"], any_p)
+        # --- next round's teacher + communication accounting (on-device) --
+        with stage("cache"):
+            prev_teacher = jnp.where(any_p, teacher, carry["prev_teacher"])
+            prev_idx = jnp.where(any_p, idx, carry["prev_idx"])
+            have_prev = jnp.logical_or(carry["have_prev"], any_p)
 
-        # --- communication accounting (all on-device) ---------------------
-        catch_up = 0.0
-        if self.use_cache:
-            catch_up = cache_lib.catch_up_bytes_device(
-                cache_prev, carry["last_sync"], part, t)
-        n_up = n_req
-        if um is not None:  # Selective-FD: uplink-only confidence gating
-            uploaded_total = jnp.sum(
-                um.astype(jnp.float32) * part_f[:, None] * miss_f[None, :])
-            n_up = uploaded_total / jnp.maximum(n_part, 1.0)
-        uplink, downlink = comm_lib.distillation_round_cost_device(
-            n_clients=n_part,
-            n_selected=float(c.public_per_round),
-            n_up_samples=n_up,
-            n_down_samples=n_req,
-            n_classes=c.n_classes,
-            uplink_bits=s.uplink_bits,
-            downlink_bits=s.downlink_bits,
-            with_cache_signals=self.use_cache,
-            catch_up_down=catch_up,
-            bytes_index=c.index_bytes,
-            uplink_codec=self.codec_up,
-            downlink_codec=self.codec_down,
-        )
-        uplink = jnp.where(any_p, uplink, 0.0)
-        downlink = jnp.where(any_p, downlink, 0.0)
-        last_sync = jnp.where(part, t, carry["last_sync"])
+            catch_up = 0.0
+            if self.use_cache:
+                catch_up = cache_lib.catch_up_bytes_device(
+                    cache_prev, carry["last_sync"], part, t)
+            n_up = n_req
+            if um is not None:  # Selective-FD: uplink-only confidence gating
+                uploaded_total = jnp.sum(
+                    um.astype(jnp.float32) * part_f[:, None]
+                    * miss_f[None, :])
+                n_up = uploaded_total / jnp.maximum(n_part, 1.0)
+            uplink, downlink = comm_lib.distillation_round_cost_device(
+                n_clients=n_part,
+                n_selected=float(c.public_per_round),
+                n_up_samples=n_up,
+                n_down_samples=n_req,
+                n_classes=c.n_classes,
+                uplink_bits=s.uplink_bits,
+                downlink_bits=s.downlink_bits,
+                with_cache_signals=self.use_cache,
+                catch_up_down=catch_up,
+                bytes_index=c.index_bytes,
+                uplink_codec=self.codec_up,
+                downlink_codec=self.codec_down,
+            )
+            uplink = jnp.where(any_p, uplink, 0.0)
+            downlink = jnp.where(any_p, downlink, 0.0)
+            last_sync = jnp.where(part, t, carry["last_sync"])
 
         # --- device-plane telemetry (pre-update last_sync; whole row
         # gated so outage rounds match the host loop's zero row) -----------
@@ -256,20 +271,21 @@ class ScannedFederatedDistillation(FederatedDistillation):
 
         # --- eval (only on scheduled rounds; lax.cond skips the rest) ------
         def _eval():
-            sa = accuracy(server_params, self.x_test, self.y_test,
-                          jnp.ones(len(self.y_test)))
-            accs = [accuracy_v(p, self.xts_c[i], self.yts_c[i],
-                               self.tmask_c[i].astype(jnp.float32))
-                    for i, p in enumerate(cp)]
-            ca = jnp.mean(self.models.concat(accs))
-            cacc = jnp.stack([jnp.mean(a) for a in accs])
-            sv = val_loss_soft(server_params, self.x_pub[self.pub_val_idx],
-                               teacher_val)
-            cv = jnp.mean(self.models.concat(
-                [val_loss_hard_v(p, self.xs_c[i], self.ys_c[i],
-                                 self.val_mask_c[i].astype(jnp.float32))
-                 for i, p in enumerate(cp)]))
-            return sa, ca, sv, cv, cacc
+            with stage("eval"):
+                sa = accuracy(server_params, self.x_test, self.y_test,
+                              jnp.ones(len(self.y_test)))
+                accs = [accuracy_v(p, self.xts_c[i], self.yts_c[i],
+                                   self.tmask_c[i].astype(jnp.float32))
+                        for i, p in enumerate(cp)]
+                ca = jnp.mean(self.models.concat(accs))
+                cacc = jnp.stack([jnp.mean(a) for a in accs])
+                sv = val_loss_soft(server_params,
+                                   self.x_pub[self.pub_val_idx], teacher_val)
+                cv = jnp.mean(self.models.concat(
+                    [val_loss_hard_v(p, self.xs_c[i], self.ys_c[i],
+                                     self.val_mask_c[i].astype(jnp.float32))
+                     for i, p in enumerate(cp)]))
+                return sa, ca, sv, cv, cacc
 
         sa, ca, sv, cv, cacc = jax.lax.cond(
             do_eval, _eval,
@@ -291,9 +307,6 @@ class ScannedFederatedDistillation(FederatedDistillation):
                   server_acc=sa, client_acc=ca, server_val=sv, client_val=cv,
                   cohort_acc=cacc, have_tv=have_tv)
         if tel is not None:
-            # per-round row out through ys, running totals in the carry
-            new_carry["telemetry"] = obs_device.accumulate(
-                carry["telemetry"], tel)
             ys["telemetry"] = tel
         return new_carry, ys
 
@@ -301,33 +314,39 @@ class ScannedFederatedDistillation(FederatedDistillation):
     def _initial_carry(self):
         """The scan carry is exactly the checkpointable engine state
         (same placeholders, same ``have_*`` flags) minus the host-side
-        round counter — one source of truth for both.  Telemetry-on
-        runs additionally carry the running RoundTelemetry totals (not
-        checkpointable state: telemetry is a per-run-leg observation,
-        zeroed at every run())."""
+        round counter — one source of truth for both."""
         carry = self.state_dict()
         del carry["t_done"]
-        if self._telemetry:
-            carry["telemetry"] = obs_device.zeros(self.models.n_cohorts)
         return carry
 
     # ------------------------------------------------------------------
     def run(self, rounds: Optional[int] = None) -> History:
+        """Run ``rounds`` more rounds as one device program.  Host work
+        is marked on the profiler's clock: ``engine.run`` around
+        ``engine.prepare`` (masks and the program's arguments),
+        ``engine.dispatch`` (the call), ``engine.wait`` (until the
+        device is done) and ``engine.finish`` (readback and History)."""
         c = self.cfg
         T = c.rounds if rounds is None else rounds
         t0 = self.t_done  # absolute round numbering (chained/restored runs)
-        ts = jnp.arange(t0 + 1, t0 + T + 1, dtype=jnp.int32)
-        offline = jnp.asarray(
-            self.scenario.offline_masks(T, c.n_clients, start=t0 + 1))
-        eval_np = np.array([(t % c.eval_every == 0) or (t == t0 + T)
-                            for t in range(t0 + 1, t0 + T + 1)], dtype=bool)
-        carry, ys = self._run_rounds(ts, offline, jnp.asarray(eval_np))
-        self.t_done = t0 + T
-        return self._finish_run(carry, ys, eval_np, t0)
-
-    def _run_rounds(self, ts, offline, do_eval):
-        """Launch the device program for the given round batch."""
-        return self._program()(*self._aot_args(ts, offline, do_eval))
+        with span("engine.run", first_round=t0 + 1, rounds=T):
+            with span("engine.prepare"):
+                eval_np = np.array([(t % c.eval_every == 0) or (t == t0 + T)
+                                    for t in range(t0 + 1, t0 + T + 1)],
+                                   dtype=bool)
+                args = self._call_args(eval_np)
+            with span("engine.dispatch"):
+                out = self._program()(*args)
+                del args  # the inputs are freed while the device runs
+            with span("engine.wait"):
+                # one execution defines every output: waiting on one leaf
+                # waits for the device, without a host wait per buffer
+                jax.block_until_ready(jax.tree_util.tree_leaves(out)[0])
+            carry, ys = out
+            self.t_done = t0 + T
+            ys_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(ys))
+            with span("engine.finish", bytes=ys_bytes):
+                return self._finish_run(carry, ys, eval_np, t0)
 
     def _program(self):
         """The jitted whole-run program (lazily built, cached); the
@@ -342,26 +361,26 @@ class ScannedFederatedDistillation(FederatedDistillation):
         """Concrete arguments matching ``_program()``'s signature."""
         return (self._initial_carry(), (ts, offline, do_eval))
 
+    def _call_args(self, do_eval: np.ndarray):
+        """The program's arguments for the ``len(do_eval)`` rounds after
+        ``t_done``."""
+        t0, T = self.t_done, len(do_eval)
+        ts = jnp.arange(t0 + 1, t0 + T + 1, dtype=jnp.int32)
+        offline = jnp.asarray(
+            self.scenario.offline_masks(T, self.cfg.n_clients, start=t0 + 1))
+        return self._aot_args(ts, offline, jnp.asarray(do_eval))
+
     def aot_lower(self, rounds: int = 1):
         """AOT-lower the round program without running it: the
         ``jax.stages.Lowered`` for a ``rounds``-round batch (no eval
         rounds).  ``.compile()`` gives optimized HLO + XLA cost analysis
         — what :mod:`benchmarks.engine_roofline` feeds the
         :mod:`repro.launch.roofline` model."""
-        c = self.cfg
-        t0 = self.t_done
-        ts = jnp.arange(t0 + 1, t0 + rounds + 1, dtype=jnp.int32)
-        offline = jnp.asarray(
-            self.scenario.offline_masks(rounds, c.n_clients, start=t0 + 1))
-        do_eval = jnp.zeros(rounds, bool)
-        return self._program().lower(*self._aot_args(ts, offline, do_eval))
+        return self._program().lower(*self._call_args(np.zeros(rounds, bool)))
 
     def _finish_run(self, carry, ys, eval_np, t0) -> History:
-        # telemetry leaves first: they are observation outputs, not
-        # engine state (the carry totals are redundant with the stack
-        # and exist to prove the accumulate path; the stack is the record)
-        carry, ys = dict(carry), dict(ys)
-        carry.pop("telemetry", None)
+        # the telemetry stack is an observation output, not engine state
+        ys = dict(ys)
         tel_stack = ys.pop("telemetry", None)
 
         # persist final device state (parity checks, chained run() calls)

@@ -60,6 +60,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import cache as cache_lib
 from repro.core import comm as comm_lib
 from repro.obs import device as obs_device
+from repro.obs.trace import stage
 from repro.fl.rounds import (
     _select_cohorts,
     accuracy,
@@ -197,12 +198,6 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
             client_params=cax, server_params=rep, cache=rep,
             prev_teacher=rep, prev_idx=rep, have_prev=rep,
             teacher_val=rep, have_tv=rep, last_sync=rep)
-        if self._telemetry:
-            # telemetry counters derive from replicated inputs (and the
-            # participant-mean gauges psum over the client axis before
-            # entering the row), so the whole pytree stays replicated —
-            # the replication checker proves it (repro.analysis)
-            carry["telemetry"] = rep
         consts = dict(
             xs=cax, ys=cax, train_mask=cax, xts=cax, yts=cax, tmask=cax,
             val_mask=cax, x_pub=rep, x_test=rep, y_test=rep, x_pub_val=rep)
@@ -237,24 +232,29 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
         K = c.n_clients
         t, offline_t, do_eval = xs
 
-        kt = jax.random.fold_in(self._key_rounds, t)
-        k_idx, k_part = jax.random.split(kt)
-        idx = jnp.sort(jax.random.choice(
-            k_idx, c.public_size, (c.public_per_round,), replace=False))
-        # Participation is drawn over the FULL client axis on every shard
-        # (replicated: same key -> same draw) — conscription ranks couple
-        # clients across shards and key-stream parity with engine="scan"
-        # requires the identical global sample — then sliced locally, one
-        # block per cohort (cohort c's shard-s clients are the global
-        # indices offset_c + s*kloc_c .. offset_c + (s+1)*kloc_c).
-        part_full = self.scenario.participation_mask_device(k_part, offline_t)
-        six = jax.lax.axis_index(CLIENT_AXIS)
-        part_c = [jax.lax.dynamic_slice_in_dim(part_full, off + six * kc, kc)
-                  for off, kc in zip(self.models.offsets, self.kloc_c)]
-        part = self.models.concat(part_c)          # shard-local (kloc,)
-        part_f = part.astype(jnp.float32)
-        n_part = jnp.sum(part_full.astype(jnp.float32))  # global, replicated
-        any_p = n_part > 0
+        with stage("cache"):
+            kt = jax.random.fold_in(self._key_rounds, t)
+            k_idx, k_part = jax.random.split(kt)
+            idx = jnp.sort(jax.random.choice(
+                k_idx, c.public_size, (c.public_per_round,), replace=False))
+            # Participation is drawn over the FULL client axis on every
+            # shard (replicated: same key -> same draw) — conscription
+            # ranks couple clients across shards and key-stream parity
+            # with engine="scan" requires the identical global sample —
+            # then sliced locally, one block per cohort (cohort c's
+            # shard-s clients are the global indices
+            # offset_c + s*kloc_c .. offset_c + (s+1)*kloc_c).
+            part_full = self.scenario.participation_mask_device(k_part,
+                                                                offline_t)
+            six = jax.lax.axis_index(CLIENT_AXIS)
+            part_c = [jax.lax.dynamic_slice_in_dim(part_full, off + six * kc,
+                                                   kc)
+                      for off, kc in zip(self.models.offsets, self.kloc_c)]
+            part = self.models.concat(part_c)          # shard-local (kloc,)
+            part_f = part.astype(jnp.float32)
+            # global, replicated
+            n_part = jnp.sum(part_full.astype(jnp.float32))
+            any_p = n_part > 0
 
         def gate(new, old):
             """Keep ``old`` wholesale on total-outage rounds."""
@@ -263,119 +263,134 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
 
         # --- clients (shard-local, per cohort): distill, then train ------
         cp = carry["client_params"]
-        x_prev = consts["x_pub"][carry["prev_idx"]]
-        upd = [distill_v(p, x_prev,
-                         jnp.broadcast_to(carry["prev_teacher"],
-                                          (kc,) + carry["prev_teacher"].shape),
-                         c.lr_dist, c.distill_steps)
-               for p, kc in zip(cp, self.kloc_c)]
-        cp = _select_cohorts(upd, cp, [jnp.logical_and(pc, carry["have_prev"])
-                                       for pc in part_c])
-        upd = self._local_train_shard(cp, t, consts)
-        cp = _select_cohorts(upd, cp, part_c)
+        with stage("client_distill"):
+            x_prev = consts["x_pub"][carry["prev_idx"]]
+            teacher_prev = carry["prev_teacher"]
+            upd = [distill_v(p, x_prev,
+                             jnp.broadcast_to(teacher_prev,
+                                              (kc,) + teacher_prev.shape),
+                             c.lr_dist, c.distill_steps)
+                   for p, kc in zip(cp, self.kloc_c)]
+            cp = _select_cohorts(
+                upd, cp, [jnp.logical_and(pc, carry["have_prev"])
+                          for pc in part_c])
+        with stage("local_train"):
+            upd = self._local_train_shard(cp, t, consts)
+            cp = _select_cohorts(upd, cp, part_c)
 
         # --- request list (replicated cache) -----------------------------
         cache_prev = carry["cache"]
-        if self.use_cache:
-            key_exp = (jax.random.fold_in(jax.random.PRNGKey(c.seed), t)
-                       if self.probabilistic_expiry else None)
-            miss = cache_lib.miss_mask(cache_prev, idx, t, self.D,
-                                       probabilistic=self.probabilistic_expiry,
-                                       key=key_exp)
-        else:
-            miss = jnp.ones(c.public_per_round, bool)
-        miss_f = miss.astype(jnp.float32)
-        n_req = jnp.sum(miss_f)
-        base, base_present = cache_lib.cached_at(cache_prev, idx)
+        with stage("cache"):
+            if self.use_cache:
+                key_exp = (jax.random.fold_in(jax.random.PRNGKey(c.seed), t)
+                           if self.probabilistic_expiry else None)
+                miss = cache_lib.miss_mask(
+                    cache_prev, idx, t, self.D,
+                    probabilistic=self.probabilistic_expiry, key=key_exp)
+            else:
+                miss = jnp.ones(c.public_per_round, bool)
+            miss_f = miss.astype(jnp.float32)
+            n_req = jnp.sum(miss_f)
+            base, base_present = cache_lib.cached_at(cache_prev, idx)
 
         # --- uplink + two-phase aggregation ------------------------------
         # the cohort axis collapses here: soft-label shapes are
         # architecture-independent, so codec/strategy/ledger code below
         # is identical to the homogeneous path
-        x_round = consts["x_pub"][idx]
-        z_all = self._predict_all(cp, x_round)         # (kloc, m, N)
-        # per-round transmit key, replicated across shards (same fold on
-        # every shard; DCE'd when the strategy ignores it)
-        z_all = s.transmit(z_all, jax.random.fold_in(kt, TRANSMIT_SALT))
-        z_tx = z_all  # as transmitted: telemetry's codec-error reference
-        if self._fused:
-            # fused fast path: codec round trip + linear moments in one
-            # round_kernel pass per shard; the psum + finalize
-            # nonlinearity are unchanged from the per-op two-phase path
-            um = s.upload_mask(z_all)
-            fbase = (round_kernel.resolve_delta_base(
-                         base, base_present, c.public_per_round, c.n_classes)
-                     if self._fused_spec["mode"] == "delta" else None)
-            partials = jax.lax.psum(
-                s.partial_aggregate_fused(z_all, part_f, self._fused_spec,
-                                          fbase, t), CLIENT_AXIS)
-        else:
-            if not self.codec_up.is_identity:
-                z_all = self.codec_up.roundtrip(z_all, base=base,
-                                                present=base_present)
-            um = s.upload_mask(z_all)
-            partials = jax.lax.psum(
-                s.partial_aggregate(z_all, part_f, um, t), CLIENT_AXIS)
-        fresh = s.finalize_aggregate(partials, t)      # replicated
-        if not self.codec_down.is_identity:
-            fresh = self.codec_down.roundtrip(fresh, base=base,
-                                              present=base_present)
+        with stage("predict"):
+            x_round = consts["x_pub"][idx]
+            z_all = self._predict_all(cp, x_round)         # (kloc, m, N)
+        with stage("aggregate"):
+            # per-round transmit key, replicated across shards (same fold
+            # on every shard; DCE'd when the strategy ignores it)
+            z_all = s.transmit(z_all, jax.random.fold_in(kt, TRANSMIT_SALT))
+            z_tx = z_all  # as transmitted: telemetry's codec-error reference
+            if self._fused:
+                # fused fast path: codec round trip + linear moments in
+                # one round_kernel pass per shard; the psum + finalize
+                # nonlinearity are unchanged from the per-op two-phase path
+                um = s.upload_mask(z_all)
+                fbase = (round_kernel.resolve_delta_base(
+                             base, base_present, c.public_per_round,
+                             c.n_classes)
+                         if self._fused_spec["mode"] == "delta" else None)
+                partials = jax.lax.psum(
+                    s.partial_aggregate_fused(z_all, part_f,
+                                              self._fused_spec, fbase, t),
+                    CLIENT_AXIS)
+            else:
+                if not self.codec_up.is_identity:
+                    z_all = self.codec_up.roundtrip(z_all, base=base,
+                                                    present=base_present)
+                um = s.upload_mask(z_all)
+                partials = jax.lax.psum(
+                    s.partial_aggregate(z_all, part_f, um, t), CLIENT_AXIS)
+            fresh = s.finalize_aggregate(partials, t)      # replicated
+            if not self.codec_down.is_identity:
+                fresh = self.codec_down.roundtrip(fresh, base=base,
+                                                  present=base_present)
 
         # --- teacher + cache + server distill (replicated) ---------------
-        cache = cache_prev
-        if self.use_cache:
-            teacher = cache_lib.assemble_teacher(cache_prev, idx, fresh, miss)
-            new_cache, _ = cache_lib.update_global_cache(
-                cache_prev, idx, teacher, miss, t)
-            cache = gate(new_cache, cache_prev)
-        else:
-            teacher = fresh
-        sp = distill(carry["server_params"], x_round, teacher,
-                     c.lr_dist, c.distill_steps)
-        server_params = gate(sp, carry["server_params"])
+        with stage("cache"):
+            cache = cache_prev
+            if self.use_cache:
+                teacher = cache_lib.assemble_teacher(cache_prev, idx, fresh,
+                                                     miss)
+                new_cache, _ = cache_lib.update_global_cache(
+                    cache_prev, idx, teacher, miss, t)
+                cache = gate(new_cache, cache_prev)
+            else:
+                teacher = fresh
+        with stage("server_distill"):
+            sp = distill(carry["server_params"], x_round, teacher,
+                         c.lr_dist, c.distill_steps)
+            server_params = gate(sp, carry["server_params"])
 
-        zv = self._predict_all(cp, consts["x_pub_val"])  # (kloc, n_val, N)
-        zv_sum = jax.lax.psum(jnp.sum(zv, axis=0), CLIENT_AXIS)
-        teacher_val = jnp.where(any_p, zv_sum / K, carry["teacher_val"])
-        have_tv = jnp.logical_or(carry["have_tv"], any_p)
+        with stage("predict"):
+            # (kloc, n_val, N)
+            zv = self._predict_all(cp, consts["x_pub_val"])
+            zv_sum = jax.lax.psum(jnp.sum(zv, axis=0), CLIENT_AXIS)
+            teacher_val = jnp.where(any_p, zv_sum / K, carry["teacher_val"])
+            have_tv = jnp.logical_or(carry["have_tv"], any_p)
 
-        prev_teacher = jnp.where(any_p, teacher, carry["prev_teacher"])
-        prev_idx = jnp.where(any_p, idx, carry["prev_idx"])
-        have_prev = jnp.logical_or(carry["have_prev"], any_p)
+        with stage("cache"):
+            prev_teacher = jnp.where(any_p, teacher, carry["prev_teacher"])
+            prev_idx = jnp.where(any_p, idx, carry["prev_idx"])
+            have_prev = jnp.logical_or(carry["have_prev"], any_p)
 
-        # --- byte accounting ---------------------------------------------
-        # last_sync and the participation draw are both replicated, so
-        # catch-up bytes are computed globally on every shard — the
-        # *identical* expression the scanned engine evaluates, hence
-        # byte-equal ledgers by construction (no psum needed)
-        catch_up = 0.0
-        if self.use_cache:
-            catch_up = cache_lib.catch_up_bytes_device(
-                cache_prev, carry["last_sync"], part_full, t)
-        n_up = n_req
-        if um is not None:  # Selective-FD: psum the uploaded-entry count
-            uploaded_total = jax.lax.psum(jnp.sum(
-                um.astype(jnp.float32) * part_f[:, None] * miss_f[None, :]),
-                CLIENT_AXIS)
-            n_up = uploaded_total / jnp.maximum(n_part, 1.0)
-        uplink, downlink = comm_lib.distillation_round_cost_device(
-            n_clients=jnp.sum(part_f),  # per-shard count; psum'd inside
-            n_selected=float(c.public_per_round),
-            n_up_samples=n_up,
-            n_down_samples=n_req,
-            n_classes=c.n_classes,
-            uplink_bits=s.uplink_bits,
-            downlink_bits=s.downlink_bits,
-            with_cache_signals=self.use_cache,
-            catch_up_down=catch_up,
-            bytes_index=c.index_bytes,
-            uplink_codec=self.codec_up,
-            downlink_codec=self.codec_down,
-            axis_name=CLIENT_AXIS,
-        )
-        uplink = jnp.where(any_p, uplink, 0.0)
-        downlink = jnp.where(any_p, downlink, 0.0)
-        last_sync = jnp.where(part_full, t, carry["last_sync"])
+            # --- byte accounting ----------------------------------------
+            # last_sync and the participation draw are both replicated,
+            # so catch-up bytes are computed globally on every shard —
+            # the *identical* expression the scanned engine evaluates,
+            # hence byte-equal ledgers by construction (no psum needed)
+            catch_up = 0.0
+            if self.use_cache:
+                catch_up = cache_lib.catch_up_bytes_device(
+                    cache_prev, carry["last_sync"], part_full, t)
+            n_up = n_req
+            if um is not None:  # Selective-FD: psum the uploaded entries
+                uploaded_total = jax.lax.psum(jnp.sum(
+                    um.astype(jnp.float32) * part_f[:, None]
+                    * miss_f[None, :]), CLIENT_AXIS)
+                n_up = uploaded_total / jnp.maximum(n_part, 1.0)
+            uplink, downlink = comm_lib.distillation_round_cost_device(
+                n_clients=jnp.sum(part_f),  # per-shard count; psum'd inside
+                n_selected=float(c.public_per_round),
+                n_up_samples=n_up,
+                n_down_samples=n_req,
+                n_classes=c.n_classes,
+                uplink_bits=s.uplink_bits,
+                downlink_bits=s.downlink_bits,
+                with_cache_signals=self.use_cache,
+                catch_up_down=catch_up,
+                bytes_index=c.index_bytes,
+                uplink_codec=self.codec_up,
+                downlink_codec=self.codec_down,
+                axis_name=CLIENT_AXIS,
+            )
+            uplink = jnp.where(any_p, uplink, 0.0)
+            downlink = jnp.where(any_p, downlink, 0.0)
+            last_sync = jnp.where(part_full, t, carry["last_sync"])
 
         # --- device-plane telemetry: counters from the replicated
         # full-width draw/last_sync, gauges from the shard-local stack
@@ -410,15 +425,16 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
                 for i, p in enumerate(cp))
             return sa, acc_sums, sv, cv_part
 
-        sa, acc_sums, sv, cv_part = jax.lax.cond(
-            do_eval, _eval_local,
-            lambda: (jnp.float32(0),
-                     jnp.zeros(self.models.n_cohorts, jnp.float32),
-                     jnp.float32(0), jnp.float32(0)))
-        acc_sums = jax.lax.psum(acc_sums, CLIENT_AXIS)  # global per cohort
-        cacc = acc_sums / jnp.asarray(self.models.sizes, jnp.float32)
-        ca = jnp.sum(acc_sums) / K
-        cv = jax.lax.psum(cv_part, CLIENT_AXIS) / K
+        with stage("eval"):
+            sa, acc_sums, sv, cv_part = jax.lax.cond(
+                do_eval, _eval_local,
+                lambda: (jnp.float32(0),
+                         jnp.zeros(self.models.n_cohorts, jnp.float32),
+                         jnp.float32(0), jnp.float32(0)))
+            acc_sums = jax.lax.psum(acc_sums, CLIENT_AXIS)  # global per cohort
+            cacc = acc_sums / jnp.asarray(self.models.sizes, jnp.float32)
+            ca = jnp.sum(acc_sums) / K
+            cv = jax.lax.psum(cv_part, CLIENT_AXIS) / K
 
         new_carry = dict(
             client_params=cp,
@@ -435,8 +451,6 @@ class ShardedFederatedDistillation(ScannedFederatedDistillation):
                   server_acc=sa, client_acc=ca, server_val=sv, client_val=cv,
                   cohort_acc=cacc, have_tv=have_tv)
         if tel is not None:
-            new_carry["telemetry"] = obs_device.accumulate(
-                carry["telemetry"], tel)
             ys["telemetry"] = tel
         return new_carry, ys
 

@@ -4,8 +4,8 @@ The scan/shard engines compile the entire run into ONE XLA program —
 nothing crosses back to the host until the stacked per-round outputs
 come out of the final ``lax.scan``.  Telemetry therefore cannot be a
 Python-side logger: every counter and gauge here is a fixed-shape jnp
-value computed *inside* the round body, stacked by the scan like any
-other ``ys`` leaf, and accumulated in the carry for running totals.
+value computed *inside* the round body and stacked by the scan like
+any other ``ys`` leaf.
 No callbacks, no dynamic shapes, no host round-trips — the static
 analyzer (``repro.analysis``) proves the instrumented round body is
 free of host-callback primitives.
@@ -38,7 +38,6 @@ __all__ = [
     "TelemetryLog",
     "zeros",
     "gate",
-    "accumulate",
     "participants_per_cohort",
     "cache_signal_counts",
     "returning_client_count",
@@ -119,7 +118,7 @@ GAUGE_FIELDS = ("teacher_entropy_pre", "teacher_entropy_post", "beta",
 
 
 def zeros(n_cohorts: int) -> RoundTelemetry:
-    """The all-zero telemetry row (outage rounds, initial carry)."""
+    """The all-zero telemetry row (outage rounds)."""
     i0 = jnp.zeros((), jnp.int32)
     f0 = jnp.zeros((), jnp.float32)
     return RoundTelemetry(
@@ -138,11 +137,6 @@ def gate(tel: RoundTelemetry, keep) -> RoundTelemetry:
     z = zeros(tel.participants.shape[0])
     return jax.tree_util.tree_map(
         lambda a, b: jnp.where(keep, a, b), tel, z)
-
-
-def accumulate(total: RoundTelemetry, tel: RoundTelemetry) -> RoundTelemetry:
-    """Running totals for the scan carry (element-wise sum)."""
-    return jax.tree_util.tree_map(lambda a, b: a + b, total, tel)
 
 
 # ---------------------------------------------------------------------------

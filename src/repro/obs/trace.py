@@ -1,26 +1,40 @@
-"""Host-plane tracing: monotonic clocks and a span tracer.
+"""Tracing: monotonic clocks, a span tracer, and names on the profiler's clock.
 
-Pure stdlib — importing this module never imports jax, so the launch
-scripts can route their timing through :func:`now` before they set
-``XLA_FLAGS`` and initialize the backend.  The opt-in
-:func:`profiler_trace` hook imports jax lazily, and only when given a
-log directory.
+Importing this module never imports jax, so the launch scripts can
+route their timing through :func:`now` before they set ``XLA_FLAGS``
+and initialize the backend.  The functions that reach the profiler
+import jax lazily:
+
+- :func:`stage` names one stage of a round body (``fl.<stage>``, a
+  ``jax.named_scope``).  It is trace-time metadata: every device
+  operation the stage lowers to carries the name in its op path, and
+  it costs nothing at run time.
+- :func:`span` marks a block of host work with a
+  ``jax.profiler.TraceAnnotation``.  Under a profiler trace the span
+  lands on the host plane, on the same clock as the device's events.
 
 Spans are recorded as a well-nested B/E event sequence *by
-construction*: ``span()`` pushes the begin event on entry and the end
-event on exit, so the exported Chrome trace (Perfetto's legacy JSON
-format) is always valid regardless of clock granularity — the
+construction*: ``SpanTracer.span()`` pushes the begin event on entry
+and the end event on exit, so the exported Chrome trace (Perfetto's
+legacy JSON format) is always valid regardless of clock granularity — the
 ``python -m repro.obs validate`` check replays exactly this stack
 discipline.
 """
 from __future__ import annotations
 
 import contextlib
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional
 
-__all__ = ["now", "Span", "SpanTracer", "profiler_trace"]
+__all__ = ["now", "Span", "SpanTracer", "STAGES", "stage", "span"]
+
+# the stages of a federated round, in the round bodies' order of work;
+# the uplink codec belongs to ``aggregate`` (the fused path does codec,
+# aggregation and sharpening in one kernel)
+STAGES = ("client_distill", "local_train", "predict", "aggregate", "cache",
+          "server_distill", "eval")
 
 
 def now() -> float:
@@ -31,6 +45,23 @@ def now() -> float:
     is arbitrary: only differences are meaningful.
     """
     return time.perf_counter()
+
+
+def stage(name: str):
+    """``jax.named_scope("fl.<name>")`` for one of :data:`STAGES`."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r} (stages: {STAGES})")
+    import jax
+
+    return jax.named_scope(f"fl.{name}")
+
+
+def span(name: str, **ids: Any):
+    """A host span on the profiler's clock
+    (``jax.profiler.TraceAnnotation``); ``ids`` become its arguments."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **ids)
 
 
 @dataclass
@@ -58,7 +89,9 @@ class SpanTracer:
     >>> trace = tr.chrome_trace()   # load in ui.perfetto.dev
 
     All clocks are :func:`now` (monotonic); timestamps in the exported
-    trace are microseconds relative to tracer construction.
+    trace are microseconds relative to tracer construction.  Once jax
+    is loaded, each span also opens :func:`span` of its name, so it
+    shows up in a profiler trace as well.
     """
 
     def __init__(self, name: str = "run",
@@ -82,24 +115,16 @@ class SpanTracer:
         self._events.append(self._event(name, "B", rel, meta))
         self._depth += 1
         sp = Span(name, rel, 0.0, self._depth - 1, dict(meta))
+        on_profiler = (span(name) if "jax" in sys.modules
+                       else contextlib.nullcontext())
         try:
-            yield sp
+            with on_profiler:
+                yield sp
         finally:
             self._depth -= 1
             sp.dur_s = now() - start
             self._events.append(self._event(name, "E", rel + sp.dur_s, {}))
             self.spans.append(sp)
-
-    def record(self, name: str, start_s: float, dur_s: float,
-               **meta: Any) -> Span:
-        """Record an already-measured interval (``start_s`` in the
-        :func:`now` clock) as a top-level span."""
-        rel = start_s - self.t0
-        self._events.append(self._event(name, "B", rel, meta))
-        self._events.append(self._event(name, "E", rel + dur_s, {}))
-        sp = Span(name, rel, dur_s, 0, dict(meta))
-        self.spans.append(sp)
-        return sp
 
     def _event(self, name: str, ph: str, rel_s: float,
                meta: Dict[str, Any]) -> Dict[str, Any]:
@@ -128,19 +153,3 @@ class SpanTracer:
 def _jsonable(v: Any) -> Any:
     return v if isinstance(v, (int, float, bool, str, type(None))) else str(v)
 
-
-@contextlib.contextmanager
-def profiler_trace(logdir: Optional[str] = None) -> Iterator[None]:
-    """Opt-in ``jax.profiler.trace`` wrapper.
-
-    A falsy ``logdir`` is a no-op (and keeps jax out of the import
-    graph entirely); otherwise the enclosed block is profiled into
-    TensorBoard/XPlane format under ``logdir``.
-    """
-    if not logdir:
-        yield
-        return
-    import jax
-
-    with jax.profiler.trace(str(logdir)):
-        yield

@@ -154,6 +154,42 @@ def test_telemetry_callback_hook_flagged():
     assert any("callback" in f.message for f in errs)
 
 
+def _telemetry_engine(tel, carried=False):
+    """The obs pass's structural subject; ``carried`` plants the fault
+    the pass forbids: the telemetry row also rides the scan carry."""
+    from repro.analysis import obs_checks
+
+    eng = obs_checks._build_engine("scarlet", {}, {"cache_duration": 2},
+                                   "identity", telemetry=tel)
+    if tel and carried:
+        body = eng._round_device
+
+        def round_with_carried_row(carry, xs):
+            new_carry, ys = body(carry, xs)
+            return dict(new_carry, telemetry=ys["telemetry"]), ys
+        eng._round_device = round_with_carried_row
+    return eng
+
+
+def test_telemetry_structure_check_clean():
+    from repro.analysis import obs_checks
+
+    got = obs_checks.check_off_on_structure("telemetry[structure]",
+                                            _telemetry_engine)
+    assert [f.level for f in got] == ["ok"], "\n".join(map(str, got))
+
+
+def test_telemetry_in_the_carry_flagged():
+    from repro.analysis import obs_checks
+
+    got = obs_checks.check_off_on_structure(
+        "fixture/telemetry-carry",
+        lambda tel: _telemetry_engine(tel, carried=True))
+    errs = [f for f in got if f.level == "error"]
+    assert any("carry out" in f.message for f in errs), (
+        "\n".join(map(str, got)))
+
+
 @pytest.mark.slow
 def test_repo_active_pass_clean():
     from repro.analysis import active_checks
