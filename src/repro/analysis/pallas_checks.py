@@ -44,6 +44,7 @@ KERNEL_MODULES = (
     "repro.kernels.round_kernel",
     "repro.kernels.distill_kernel",
     "repro.kernels.attn_kernel",
+    "repro.kernels.mlp_distill_kernel",
 )
 
 # single-buffer warn threshold: half of VMEM, leaving the compiler room

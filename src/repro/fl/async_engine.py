@@ -162,9 +162,9 @@ class AsyncFederatedDistillation(ScannedFederatedDistillation):
         cp = carry["client_params"]
         with stage("client_distill"):
             x_prev = self.x_pub[carry["prev_idx"]]
-            upd = self._distill_all(cp, x_prev, carry["prev_teacher"])
-            cp = _select_cohorts(upd, cp, self.models.split(
-                jnp.logical_and(dispatch, carry["have_prev"])))
+            cp = self._distill_all(
+                cp, x_prev, carry["prev_teacher"],
+                jnp.logical_and(dispatch, carry["have_prev"]))
         with stage("local_train"):
             upd = self._local_train_all(cp, t)
             cp = _select_cohorts(upd, cp, self.models.split(dispatch))

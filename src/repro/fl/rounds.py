@@ -51,6 +51,7 @@ from repro.fl.config import FLConfig
 from repro.fl.scenarios import Scenario
 from repro.fl.strategies import base as strat_base
 from repro.fl.strategies.base import Strategy
+from repro.kernels import mlp_distill_kernel
 from repro.models.resnet import apply_mlp, init_mlp
 
 
@@ -276,6 +277,9 @@ class FederatedDistillation:
         # smuggle host callbacks into the compiled round.
         self._telemetry = bool(cfg.telemetry)
         self.telemetry_hook = None
+        # clients per round whose distillation the traced round runs in
+        # the VMEM kernel (set by _distill_all when the round is traced)
+        self.distill_kernel_clients = 0
         self._setup()
 
     # ------------------------------------------------------------------
@@ -503,17 +507,36 @@ class FederatedDistillation:
         self.last_sync = np.asarray(state["last_sync"]).astype(np.int64)
 
     # ------------------------------------------------------------------
-    def _distill_all(self, params, x_prev, pteach):
-        """Per-cohort client distillation on a shared ``(m, N)`` teacher
-        or per-client ``(K, m, N)`` teacher stack (COMET)."""
+    def _distill_all(self, params, x_prev, pteach, keep):
+        """Per-cohort client distillation of the clients in ``keep``
+        (``(K,)`` bool; the others keep their params) on a shared
+        ``(m, N)`` teacher or per-client ``(K, m, N)`` teacher stack
+        (COMET).
+
+        A cohort whose shapes the VMEM kernel takes on this backend
+        (``mlp_distill_kernel.use_kernel``) runs ``mlp_distill``; every
+        other cohort runs ``distill_v``.  The number of clients the
+        kernel takes is recorded when the round is traced
+        (``distill_kernel_clients``)."""
         c = self.cfg
-        if jnp.ndim(pteach) == 3:
-            teach_c = self.models.split(pteach)
-        else:
-            teach_c = [jnp.broadcast_to(pteach, (n,) + pteach.shape)
-                       for n in self.models.sizes]
-        return [distill_v(p, x_prev, teach_c[i], c.lr_dist, c.distill_steps)
-                for i, p in enumerate(params)]
+        keep_c = self.models.split(keep)
+        per_client = jnp.ndim(pteach) == 3
+        teach_c = self.models.split(pteach) if per_client else None
+        out, n_kernel = [], 0
+        for i, p in enumerate(params):
+            t = teach_c[i] if per_client else pteach
+            if mlp_distill_kernel.use_kernel(p, x_prev.shape[0]):
+                out.append(mlp_distill_kernel.mlp_distill(
+                    p, x_prev, t, keep_c[i], lr=c.lr_dist,
+                    steps=c.distill_steps))
+                n_kernel += self.models.sizes[i]
+            else:
+                if not per_client:
+                    t = jnp.broadcast_to(t, (self.models.sizes[i],) + t.shape)
+                out.append(_select(distill_v(p, x_prev, t, c.lr_dist,
+                                             c.distill_steps), p, keep_c[i]))
+        self.distill_kernel_clients = n_kernel
+        return out
 
     def _predict_all(self, params, x):
         """Cohort-collapsing soft predictions: ``(K, |x|, N)`` in global
@@ -637,8 +660,7 @@ class FederatedDistillation:
         if self.prev_teacher is not None:
             pidx, pteach = self.prev_teacher
             x_prev = self.x_pub[jnp.asarray(pidx)]
-            upd = self._distill_all(new_params, x_prev, pteach)
-            new_params = _select_cohorts(upd, new_params, part_c)
+            new_params = self._distill_all(new_params, x_prev, pteach, part_j)
         upd = self._local_train_all(new_params, t)
         self.client_params = _select_cohorts(upd, new_params, part_c)
 

@@ -141,9 +141,9 @@ class ScannedFederatedDistillation(FederatedDistillation):
         part_c = self.models.split(part)
         with stage("client_distill"):
             x_prev = self.x_pub[carry["prev_idx"]]
-            upd = self._distill_all(cp, x_prev, carry["prev_teacher"])
-            cp = _select_cohorts(upd, cp, self.models.split(
-                jnp.logical_and(part, carry["have_prev"])))
+            cp = self._distill_all(
+                cp, x_prev, carry["prev_teacher"],
+                jnp.logical_and(part, carry["have_prev"]))
         with stage("local_train"):
             upd = self._local_train_all(cp, t)
             cp = _select_cohorts(upd, cp, part_c)
@@ -325,11 +325,13 @@ class ScannedFederatedDistillation(FederatedDistillation):
         is marked on the profiler's clock: ``engine.run`` around
         ``engine.prepare`` (masks and the program's arguments),
         ``engine.dispatch`` (the call), ``engine.wait`` (until the
-        device is done) and ``engine.finish`` (readback and History)."""
+        device is done) and ``engine.finish`` (readback and History).
+        ``engine.run`` also carries ``distill_kernel_clients``, set once
+        the program is traced."""
         c = self.cfg
         T = c.rounds if rounds is None else rounds
         t0 = self.t_done  # absolute round numbering (chained/restored runs)
-        with span("engine.run", first_round=t0 + 1, rounds=T):
+        with span("engine.run", first_round=t0 + 1, rounds=T) as run_span:
             with span("engine.prepare"):
                 eval_np = np.array([(t % c.eval_every == 0) or (t == t0 + T)
                                     for t in range(t0 + 1, t0 + T + 1)],
@@ -338,6 +340,8 @@ class ScannedFederatedDistillation(FederatedDistillation):
             with span("engine.dispatch"):
                 out = self._program()(*args)
                 del args  # the inputs are freed while the device runs
+            run_span.set_metadata(
+                distill_kernel_clients=self.distill_kernel_clients)
             with span("engine.wait"):
                 # one execution defines every output: waiting on one leaf
                 # waits for the device, without a host wait per buffer

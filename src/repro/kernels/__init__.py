@@ -6,6 +6,10 @@
 - distill_kernel: soft-target CE over large (LM-vocab) class dims
                   (flash-softmax block accumulation)
 - attn_kernel:    causal GQA flash attention for client forward passes
+- round_kernel:   fused uplink codec + client reduction + sharpening of
+                  a round (the scan engines' fused_round path)
+- mlp_distill_kernel: every client's whole SGD distillation run in VMEM
+                  (the engines' client distillation on TPU)
 
 ops.py = jit'd wrappers (interpret mode on CPU); ref.py = jnp oracles.
 """

@@ -88,3 +88,44 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p, vr.astype(jnp.float32))
     return o.astype(q.dtype)
+
+
+def mlp_distill(params, x: jnp.ndarray, teacher: jnp.ndarray,
+                keep: jnp.ndarray, lr: float, steps: int):
+    """Oracle for ``mlp_distill_kernel.mlp_distill``: ``steps`` SGD steps
+    of every kept client on ``rounds._kl``, rows x features, with each
+    matmul's operands rounded to bfloat16 and accumulated in float32
+    (gradients written out by hand: autodiff through the casts would
+    round the weight gradients to bfloat16 too)."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    n = sum(1 for k in params if k.startswith("w"))
+
+    def mm(a, b):
+        return jnp.dot(a.astype(bf16), b.astype(bf16),
+                       preferred_element_type=f32)
+
+    def one(p, t):
+        t = jnp.clip(t, _EPS, 1.0)
+        for _ in range(steps):
+            acts, pre = [x], []
+            for i in range(n):
+                h = mm(acts[i], p[f"w{i}"]) + p[f"b{i}"]
+                if i < n - 1:
+                    pre.append(h)
+                    acts.append(jnp.maximum(h, 0.0))
+            sm = jax.nn.softmax(h, axis=-1)
+            g = (sm * t.sum(axis=-1, keepdims=True) - t) / x.shape[0]
+            new = dict(p)
+            for i in reversed(range(n)):
+                new[f"w{i}"] = p[f"w{i}"] - lr * mm(acts[i].T, g)
+                new[f"b{i}"] = p[f"b{i}"] - lr * g.sum(axis=0)
+                if i > 0:
+                    g = jnp.where(pre[i - 1] > 0, mm(g, p[f"w{i}"].T), 0.0)
+            p = new
+        return p
+
+    out = jax.vmap(one, in_axes=(0, 0 if teacher.ndim == 3 else None))(
+        params, teacher.astype(f32))
+    return jax.tree_util.tree_map(
+        lambda a, b: jnp.where(keep.reshape((-1,) + (1,) * (a.ndim - 1)), a, b),
+        out, params)

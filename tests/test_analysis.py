@@ -96,7 +96,7 @@ def test_repo_pallas_pass_clean():
     assert not errs, "\n".join(str(f) for f in errs)
     # every kernel module contributed at least one linted case
     subjects = {f.subject.split("/")[0] for f in findings}
-    for mod in ("era_fused", "quant", "round", "distill", "attn"):
+    for mod in ("era_fused", "quant", "round", "distill", "attn", "mlp_distill"):
         assert any(s.startswith(mod.split("_")[0]) for s in subjects), mod
 
 

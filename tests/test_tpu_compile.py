@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import era_kernel, quant_kernel, round_kernel
+from repro.kernels import era_kernel, mlp_distill_kernel, quant_kernel, round_kernel
 
 
 @pytest.fixture(scope="module")
@@ -86,3 +86,26 @@ def test_fused_round_compiles(one_chip, mode, bits, K):
             z, w, 1.5, mode=mode, bits=bits, interpret=False)
         shapes = ((K, M, N), (K,))
     assert "tpu_custom_call" in _compile_text(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("per_client", [False, True], ids=["shared", "per_client"])
+def test_mlp_distill_compiles(one_chip, per_client):
+    """Client distillation at FedAvg's 2NN widths on 1,000 public rows:
+    the working set a client holds in VMEM fits, and layer 0's weight
+    stack enters the kernel with no layout copy (its (K, 784, 200) stack
+    keeps 784 minor, which is the kernel's W0^T block)."""
+    K, m, widths = 100, 1000, (784, 200, 200, 10)
+    S = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    params = {}
+    for i, (a, c) in enumerate(zip(widths[:-1], widths[1:])):
+        params[f"w{i}"], params[f"b{i}"] = S((K, a, c)), S((K, c))
+    t = S((K, m, 10) if per_client else (m, 10))
+    text = jax.jit(
+        lambda p, x, t, keep: mlp_distill_kernel.mlp_distill(
+            p, x, t, keep, lr=0.1, steps=5, interpret=False),
+        donate_argnums=0).lower(params, S((m, 784)), t,
+                                S((K,), jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "f32[100,784,200]" in ln.split(" copy(")[0]]
