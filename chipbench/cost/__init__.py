@@ -6,44 +6,25 @@ scheduled rounds.  A matmul of ``(n, a) @ (a, c)`` is ``2 n a c``
 operations; bias adds, activations and the softmax are not counted.  A
 training step on one row is the forward pass, the weight gradients and
 the input gradients of every layer but the first (the data needs no
-gradient).
+gradient).  The per-row counts are the configuration's family file's
+(``forward_flops``, ``train_step_flops``), the valid rows per client its
+partition file's (``rows``), the test set's length its inputs file's
+(``n_test``).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List
+from pathlib import Path
+from typing import Any, Dict
 
 import numpy as np
 
-
-def mlp_dims(config: Dict[str, Any]) -> List[int]:
-    return [config["dim"]] + [config["hidden"]] * config["mlp_depth"] + [config["n_classes"]]
+from chipbench import spec
 
 
-def param_count(dims: List[int]) -> int:
-    return sum(a * c + c for a, c in zip(dims[:-1], dims[1:]))
-
-
-def forward_flops(dims: List[int]) -> int:
-    """Per row."""
-    return sum(2 * a * c for a, c in zip(dims[:-1], dims[1:]))
-
-
-def train_step_flops(dims: List[int]) -> int:
-    """Per row: forward, weight gradients, input gradients of layers 2..L."""
-    layers = list(zip(dims[:-1], dims[1:]))
-    return (forward_flops(dims) + sum(2 * a * c for a, c in layers)
-            + sum(2 * a * c for a, c in layers[1:]))
-
-
-def uniform_rows(n_samples: int, n_clients: int) -> np.ndarray:
-    """Valid rows per client under the round-robin partition."""
-    base, extra = divmod(n_samples, n_clients)
-    return base + (np.arange(n_clients) < extra).astype(np.int64)
-
-
-def train_rows(n_samples: int, n_clients: int) -> np.ndarray:
-    """Rows each client trains on: the first 90% of its valid rows."""
-    n = uniform_rows(n_samples, n_clients).astype(np.float32)
+def train_rows(rows: np.ndarray) -> np.ndarray:
+    """Rows each client trains on: the first 90% of its valid ``rows``
+    (the engine's split, whatever the partition)."""
+    n = rows.astype(np.float32)
     return np.maximum((n * np.float32(0.9)).astype(np.int64), 1)
 
 
@@ -53,14 +34,15 @@ def participants(config: Dict[str, Any], traffic: Dict[str, Any]) -> int:
 
 
 def round_flops(config: Dict[str, Any], traffic: Dict[str, Any], *,
-                eval_round: bool, distill: bool = True) -> float:
+                eval_round: bool, distill: bool = True, root: Path = spec.REPO) -> float:
     """Operations one round needs; ``distill`` is false only on round 1."""
-    dims = mlp_dims(config)
-    fwd, step = forward_flops(dims), train_step_flops(dims)
+    parts = spec.parts(config, root)
+    fwd = parts["family"].forward_flops(config)
+    step = parts["family"].train_step_flops(config)
     k, m = config["n_clients"], participants(config, traffic)
     pub_t = config["public_per_round"]
-    n_priv = config["private_size"]
-    rows = train_rows(n_priv, k)
+    valid = parts["partition"].rows(config["private_size"], k)
+    rows = train_rows(valid)
     mean_train = float(np.mean(rows))
     total = m * config["local_steps"] * mean_train * step        # local training
     if distill:
@@ -68,9 +50,9 @@ def round_flops(config: Dict[str, Any], traffic: Dict[str, Any], *,
     total += m * pub_t * fwd                                      # uplink predictions
     total += config["distill_steps"] * pub_t * step               # server distillation
     if eval_round:
-        n_test = max(n_priv // 5, 200)
+        n_test = parts["inputs"].n_test(config)
         n_val_pub = max(config["public_size"] // 10, 10)
-        val_rows = n_priv - int(rows.sum())
+        val_rows = int(valid.sum()) - int(rows.sum())
         total += (n_test                  # server accuracy
                   + n_test                # every client's accuracy on its test rows
                   + val_rows              # every client's validation loss
@@ -79,15 +61,16 @@ def round_flops(config: Dict[str, Any], traffic: Dict[str, Any], *,
     return float(total)
 
 
-def call_flops(config: Dict[str, Any], traffic: Dict[str, Any]) -> float:
+def call_flops(config: Dict[str, Any], traffic: Dict[str, Any], *,
+               root: Path = spec.REPO) -> float:
     """Operations of one ``run(R)`` call after the first: every round
     distills, and rounds on the evaluation schedule (or the call's last)
     evaluate.  Calls start at multiples of ``R``, so the schedule is the
     same in every call."""
     r, every = traffic["rounds_per_call"], traffic["eval_every"]
     n_eval = sum(1 for t in range(1, r + 1) if t % every == 0 or t == r)
-    return (n_eval * round_flops(config, traffic, eval_round=True)
-            + (r - n_eval) * round_flops(config, traffic, eval_round=False))
+    return (n_eval * round_flops(config, traffic, eval_round=True, root=root)
+            + (r - n_eval) * round_flops(config, traffic, eval_round=False, root=root))
 
 
 # per element of the client stack: residual, min/max, quantize and

@@ -1,17 +1,21 @@
-"""The benchmark's own copy of the synthetic data generator.
+"""Gaussian class clusters: the benchmark's own copy of the program's
+synthetic data generator.
 
-The plain reference (``chipbench.reference``) rebuilds every input from
-the seed and takes nothing the system under test made, so it needs the
-generator that the system's configuration names: Gaussian class
-clusters for the private set, shifted clusters for the public set, a
-held-out test set from the private distribution, and the round-robin
-("uniform") client partition.  This is the same arithmetic as the
-program's generator, kept here so that a change there cannot move the
-yardstick.
+The plain reference rebuilds every input from the seed and takes nothing
+the system under test made.  This inputs file makes float32 rows of
+``dim`` features: Gaussian class clusters for the private set, shifted
+clusters for the public set, and a held-out test set from the private
+distribution.  It is the same arithmetic as the program's generator,
+kept here so that a change there cannot move the yardstick.
+
+An inputs file gives ``make(config, seed)`` (``x_private``,
+``y_private``, ``x_public``, ``x_test``, ``y_test``: ``x`` arrays in
+their own dtype, ``y`` int32) and ``n_test(config)`` (the test set's
+length).
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Any, Dict
 
 import numpy as np
 
@@ -49,18 +53,11 @@ def public_private(n_private: int, n_public: int, n_classes: int, dim: int,
             "x_test": xt, "y_test": yt}
 
 
-def uniform_shards(x: np.ndarray, y: np.ndarray, n_clients: int
-                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Sample ``i`` goes to client ``i % K`` at slot ``i // K``:
-    ``(xs (K, n_max, ...), ys (K, n_max), valid (K, n_max))``."""
-    n = len(y)
-    n_max = -(-n // n_clients)
-    total = n_clients * n_max
-    xs = np.zeros((total,) + x.shape[1:], x.dtype)
-    ys = np.zeros((total,), y.dtype)
-    valid = np.zeros((total,), bool)
-    xs[:n], ys[:n], valid[:n] = x, y, True
-    perm = (1, 0) + tuple(range(2, xs.ndim + 1))
-    xs = xs.reshape((n_max, n_clients) + x.shape[1:]).transpose(perm)
-    return (np.ascontiguousarray(xs), np.ascontiguousarray(ys.reshape(n_max, n_clients).T),
-            np.ascontiguousarray(valid.reshape(n_max, n_clients).T))
+def n_test(config: Dict[str, Any]) -> int:
+    return max(config["private_size"] // 5, 200)
+
+
+def make(config: Dict[str, Any], seed: int) -> Dict[str, np.ndarray]:
+    return public_private(config["private_size"], config["public_size"],
+                          config["n_classes"], config["dim"], seed,
+                          config["cluster_scale"], config["noise"])

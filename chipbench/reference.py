@@ -3,8 +3,10 @@ soft-label cache, in straightforward ``jax.numpy``.
 
 It imports nothing of the system under test and takes nothing it made:
 data, partition, initial weights, participation and public-subset draws
-are all rebuilt here from the seed, following the configuration.  Per
-round ``t`` (SCARLET Alg. 1 with the Alg.-3 expiry test):
+are all rebuilt here from the seed, following the configuration.  The
+client model, the inputs and the partition are the files the
+configuration names (``chipbench.spec.parts``); the round below knows no
+model.  Per round ``t`` (SCARLET Alg. 1 with the Alg.-3 expiry test):
 
 1. the key ``fold_in(fold_in(PRNGKey(seed), 43), t)`` splits into the
    public-subset key (``|P^t|`` of ``|P|``, sorted) and the
@@ -25,19 +27,21 @@ round ``t`` (SCARLET Alg. 1 with the Alg.-3 expiry test):
 
 ``dtype="float32"`` runs with every matmul at ``highest`` precision;
 ``dtype="bfloat16"`` is the lower-precision control: parameters, data and
-arithmetic in bfloat16.
+arithmetic in bfloat16.  Inputs that are not floating point, such as
+token ids, keep their own dtype.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Dict, List
+import functools
+from pathlib import Path
+from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import data as bench_data
+from chipbench import spec
 from chipbench.compare import leaf_change
 
 NEVER = -(2 ** 30)
@@ -46,12 +50,12 @@ EVAL_CHUNK = 512  # clients per evaluation block
 
 @dataclasses.dataclass(frozen=True)
 class Setting:
-    """What one reference run needs, from a configuration and a mix."""
+    """What one reference run needs, from a configuration and a mix.  The
+    configuration's family, inputs and partition read their own keys
+    from ``config``."""
+    config: Dict[str, Any]
     n_clients: int
     n_classes: int
-    dim: int
-    hidden: int
-    depth: int
     public_size: int
     public_per_round: int
     private_size: int
@@ -59,8 +63,6 @@ class Setting:
     distill_steps: int
     lr: float
     lr_dist: float
-    cluster_scale: float
-    noise: float
     beta: float
     cache_duration: int
     quant_bits: int
@@ -82,32 +84,19 @@ class Result:
     cache_present: np.ndarray
 
 
-def _mlp_init(key, dims, dtype):
-    params = {}
-    for i, (a, c) in enumerate(zip(dims[:-1], dims[1:])):
-        key, k1 = jax.random.split(key)
-        params[f"w{i}"] = (jax.random.normal(k1, (a, c)) * math.sqrt(2.0 / a)).astype(dtype)
-        params[f"b{i}"] = jnp.zeros((c,), dtype)
-    return params
+def _cast(a: np.ndarray, dt):
+    """Floating-point inputs in the run's dtype; others as they are."""
+    return jnp.asarray(a, dt) if np.issubdtype(a.dtype, np.floating) else jnp.asarray(a)
 
 
-def _logits(p, x):
-    n = len(p) // 2
-    for i in range(n):
-        x = x @ p[f"w{i}"] + p[f"b{i}"]
-        if i < n - 1:
-            x = jax.nn.relu(x)
-    return x
-
-
-def _ce(p, x, y, mask):
-    logp = jax.nn.log_softmax(_logits(p, x), axis=-1)
+def _ce(logits, p, x, y, mask):
+    logp = jax.nn.log_softmax(logits(p, x), axis=-1)
     nll = -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def _kl(p, x, teacher):
-    logp = jax.nn.log_softmax(_logits(p, x), axis=-1)
+def _kl(logits, p, x, teacher):
+    logp = jax.nn.log_softmax(logits(p, x), axis=-1)
     t = jnp.clip(teacher, 1e-12, 1.0)
     return jnp.mean(jnp.sum(t * (jnp.log(t) - logp), axis=-1))
 
@@ -141,36 +130,38 @@ def _sharpen(zbar, beta):
 class Reference:
     """One federation from one seed; ``run(rounds)`` plays its first rounds."""
 
-    def __init__(self, s: Setting, seed: int, dtype: str = "float32"):
+    def __init__(self, s: Setting, seed: int, dtype: str = "float32",
+                 root: Path = spec.REPO):
         self.s, self.seed = s, seed
         self.dtype = jnp.dtype(dtype)
         dt = self.dtype
-        d = bench_data.public_private(s.private_size, s.public_size, s.n_classes,
-                                      s.dim, seed, s.cluster_scale, s.noise)
-        xs, ys, valid = bench_data.uniform_shards(d["x_private"], d["y_private"],
-                                                  s.n_clients)
-        xts, yts, tvalid = bench_data.uniform_shards(d["x_test"], d["y_test"],
-                                                     s.n_clients)
+        parts = spec.parts(s.config, root)
+        family, shards = parts["family"], parts["partition"].shards
+        self.logits = family.logits
+        self._ce = functools.partial(_ce, family.logits)
+        self._kl = functools.partial(_kl, family.logits)
+        d = parts["inputs"].make(s.config, seed)
+        xs, ys, valid = shards(d["x_private"], d["y_private"], s.n_clients)
+        xts, yts, tvalid = shards(d["x_test"], d["y_test"], s.n_clients)
         n_valid = valid.sum(1).astype(np.float32)
         cut = np.maximum((n_valid * np.float32(0.9)).astype(np.int32), 1)
         pos = np.arange(valid.shape[1])[None, :]
-        self.xs = jnp.asarray(xs, dt)
+        self.xs = _cast(xs, dt)
         self.ys = jnp.asarray(ys)
         self.train_mask = jnp.asarray(valid & (pos < cut[:, None]), dt)
         self.val_mask = jnp.asarray(valid & (pos >= cut[:, None]), dt)
-        self.xts, self.yts = jnp.asarray(xts, dt), jnp.asarray(yts)
+        self.xts, self.yts = _cast(xts, dt), jnp.asarray(yts)
         self.tmask = jnp.asarray(tvalid, dt)
-        self.x_pub = jnp.asarray(d["x_public"], dt)
-        self.x_test, self.y_test = jnp.asarray(d["x_test"], dt), jnp.asarray(d["y_test"])
+        self.x_pub = _cast(d["x_public"], dt)
+        self.x_test, self.y_test = _cast(d["x_test"], dt), jnp.asarray(d["y_test"])
         n_val = max(s.public_size // 10, 10)
         self.pub_val_idx = jnp.asarray(np.random.default_rng(seed + 99).choice(
             s.public_size, n_val, replace=False))
         del d, xs, xts
 
-        dims = [s.dim] + [s.hidden] * s.depth + [s.n_classes]
         keys = jax.random.split(jax.random.PRNGKey(seed), s.n_clients + 1)
-        self.clients = jax.vmap(lambda k: _mlp_init(k, dims, dt))(keys[:-1])
-        self.server = _mlp_init(keys[-1], dims, dt)
+        self.clients = jax.vmap(lambda k: family.init(k, s.config, dt))(keys[:-1])
+        self.server = family.init(keys[-1], s.config, dt)
         self.clients0 = jax.tree_util.tree_map(jnp.copy, self.clients)
         self.server0 = self.server
         self.values = jnp.zeros((s.public_size, s.n_classes), dt)
@@ -204,9 +195,9 @@ class Reference:
         p = jax.tree_util.tree_map(lambda a: a[sel], clients)
         if have_prev:
             x_prev = x_pub[prev[0]]
-            p = jax.vmap(lambda q: _sgd(_kl, q, (x_prev, prev[1]), lr_dist,
+            p = jax.vmap(lambda q: _sgd(self._kl, q, (x_prev, prev[1]), lr_dist,
                                         s.distill_steps))(p)
-        p = jax.vmap(lambda q, x, y, m: _sgd(_ce, q, (x, y, m), lr, s.local_steps))(
+        p = jax.vmap(lambda q, x, y, m: _sgd(self._ce, q, (x, y, m), lr, s.local_steps))(
             p, xs[sel], ys[sel], train_mask[sel])
         clients = jax.tree_util.tree_map(lambda a, b: a.at[sel].set(b), clients, p)
 
@@ -214,7 +205,7 @@ class Reference:
         miss = jnp.logical_not(fresh_ok)
         base, base_present = values[idx], present[idx]
         x_round = x_pub[idx]
-        z = jax.vmap(lambda q: jax.nn.softmax(_logits(q, x_round), axis=-1))(p)
+        z = jax.vmap(lambda q: jax.nn.softmax(self.logits(q, x_round), axis=-1))(p)
         levels = jnp.asarray(2 ** s.quant_bits - 1, dt)
         z = jax.vmap(lambda zk: _wire(zk, base, base_present, levels))(z)
         fresh = _sharpen(jnp.sum(z, axis=0) / jnp.asarray(z.shape[0], dt),
@@ -223,16 +214,16 @@ class Reference:
         values = values.at[idx].set(teacher)
         ts = ts.at[idx].set(jnp.where(miss, t, ts[idx]))
         present = present.at[idx].set(True)
-        server = _sgd(_kl, server, (x_round, teacher), lr_dist, s.distill_steps)
+        server = _sgd(self._kl, server, (x_round, teacher), lr_dist, s.distill_steps)
         return clients, server, (values, ts, present), teacher, jnp.sum(miss)
 
     def _eval_chunk_fn(self, p, xts, yts, tmask, xs, ys, vmask, x_val):
         def one(q, xt, yt, tm, x, y, vm):
-            ok = (jnp.argmax(_logits(q, xt), axis=-1) == yt).astype(jnp.float32)
+            ok = (jnp.argmax(self.logits(q, xt), axis=-1) == yt).astype(jnp.float32)
             acc = jnp.sum(ok * tm.astype(jnp.float32)) / jnp.maximum(
                 jnp.sum(tm.astype(jnp.float32)), 1.0)
-            zv = jax.nn.softmax(_logits(q, x_val), axis=-1)
-            return acc, _ce(q, x, y, vm).astype(jnp.float32), zv
+            zv = jax.nn.softmax(self.logits(q, x_val), axis=-1)
+            return acc, self._ce(q, x, y, vm).astype(jnp.float32), zv
         acc, vl, zv = jax.vmap(one)(p, xts, yts, tmask, xs, ys, vmask)
         return jnp.sum(acc), jnp.sum(vl), jnp.sum(zv.astype(jnp.float32), axis=0)
 
@@ -250,10 +241,10 @@ class Reference:
             acc, vl = acc + float(a), vl + float(v)
             zsum = z if zsum is None else zsum + z
         teacher_val = (zsum / s.n_clients).astype(dt)
-        ok = jnp.argmax(_logits(self.server, self.x_test), axis=-1) == self.y_test
+        ok = jnp.argmax(self.logits(self.server, self.x_test), axis=-1) == self.y_test
         return {"server_acc": float(jnp.mean(ok)),
                 "client_acc": acc / s.n_clients,
-                "server_val_loss": float(_kl(self.server, x_val, teacher_val)),
+                "server_val_loss": float(self._kl(self.server, x_val, teacher_val)),
                 "client_val_loss": vl / s.n_clients}
 
     def run(self, rounds: int) -> Result:

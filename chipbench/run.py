@@ -87,10 +87,11 @@ def first_call(engine, rounds: int) -> Tuple[Dict[str, Any], float]:
     return rec, bookkeeping + time.perf_counter() - t1
 
 
-def reference_result(cell: spec.Cell, seed: int, dtype: str = "float32"):
+def reference_result(cell: spec.Cell, seed: int, dtype: str = "float32",
+                     root: Path = spec.REPO):
     from chipbench import reference, system
     ref = reference.Reference(system.reference_setting(cell.config, cell.traffic),
-                              system.engine_seed(seed), dtype=dtype)
+                              system.engine_seed(seed), dtype=dtype, root=root)
     return ref.run(cell.traffic["rounds_per_call"])
 
 
@@ -160,7 +161,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         shutil.rmtree(logdir, ignore_errors=True)
         rec = {"trace": t, "config": cell.config, "traffic": cell.traffic,
                "calls": calls, "rounds": calls * r, "chips": len(devs),
-               "peak": spec.peaks(devs[0].device_kind, root)}
+               "peak": spec.peaks(devs[0].device_kind, root), "root": root}
         for m in cell.per_layer:
             v = spec.metric_reader(m["name"], root)(rec)
             if v is not None:
@@ -175,7 +176,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
 
     t_ref = time.perf_counter()
-    ref = reference_result(cell, seed)
+    ref = reference_result(cell, seed, root=root)
     correct, table, lines = compare.judge(compare.readings(first, ref), cell.limits)
     lines[:0] = [timing, f"window: {calls} calls of {r} rounds in {t1 - t0!r} s, "
                          f"{window_compiles} compilations; set-up {setup_s!r} s; "

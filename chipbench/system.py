@@ -9,14 +9,10 @@ the last round it finished.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict
 
 import numpy as np
-
-FL_KEYS = ("n_clients", "n_classes", "dim", "hidden", "mlp_depth", "local_steps",
-           "distill_steps", "lr", "lr_dist", "public_size", "public_per_round",
-           "private_size", "cluster_scale", "noise", "partition", "uplink_codec",
-           "downlink_codec", "index_bytes")
 
 
 def engine_seed(seed: int) -> int:
@@ -29,19 +25,29 @@ def participation_rate(config: Dict[str, Any], traffic: Dict[str, Any]) -> float
     return cost.participants(config, traffic) / config["n_clients"]
 
 
+def engine_config(config: Dict[str, Any], traffic: Dict[str, Any], seed: int):
+    """The program's ``FLConfig`` for one cell: every key of the
+    configuration that names one of its fields, and the mix's."""
+    from repro.fl import FLConfig
+
+    fields = {f.name for f in dataclasses.fields(FLConfig)}
+    return FLConfig(seed=engine_seed(seed), eval_every=traffic["eval_every"],
+                    rounds=traffic["rounds_per_call"],
+                    participation=participation_rate(config, traffic),
+                    fused_round=bool(traffic["fused_round"]),
+                    **{k: v for k, v in config.items() if k in fields})
+
+
 def build_engine(config: Dict[str, Any], traffic: Dict[str, Any], seed: int):
     """The engine for one cell, constructed as ``run_method`` does.  The
     program has no public constructor that ``run_method`` shares, so this
     repeats its construction from the same engine table."""
-    from repro.fl import FLConfig, Scenario, fixed_fraction, full_participation
+    from repro.fl import Scenario, fixed_fraction, full_participation
     from repro.fl.api import _ENGINES
     from repro.fl.strategies import STRATEGIES
 
-    rate = participation_rate(config, traffic)
-    kw = {k: config[k] for k in FL_KEYS}
-    cfg = FLConfig(seed=engine_seed(seed), eval_every=traffic["eval_every"],
-                   rounds=traffic["rounds_per_call"], participation=rate,
-                   fused_round=bool(traffic["fused_round"]), **kw)
+    cfg = engine_config(config, traffic, seed)
+    rate = cfg.participation
     part = full_participation() if rate >= 1.0 else fixed_fraction(rate)
     strategy = STRATEGIES[config["method"]](beta=config["beta"])
     cls = _ENGINES[traffic["engine"]]
@@ -88,16 +94,14 @@ def reference_setting(config: Dict[str, Any], traffic: Dict[str, Any]):
     if not codec.startswith("cache_delta+quant") or config["downlink_codec"] != "identity":
         raise ValueError(f"the reference models cache_delta+quantB uplink and an "
                          f"identity downlink, not {codec!r}/{config['downlink_codec']!r}")
-    if config["partition"] != "uniform" or config["method"] != "scarlet":
-        raise ValueError("the reference models SCARLET on the uniform partition")
+    if config["method"] != "scarlet":
+        raise ValueError(f"the reference models SCARLET, not {config['method']!r}")
     from chipbench import cost
     return Setting(
-        n_clients=config["n_clients"], n_classes=config["n_classes"], dim=config["dim"],
-        hidden=config["hidden"], depth=config["mlp_depth"],
+        config=config, n_clients=config["n_clients"], n_classes=config["n_classes"],
         public_size=config["public_size"], public_per_round=config["public_per_round"],
         private_size=config["private_size"], local_steps=config["local_steps"],
         distill_steps=config["distill_steps"], lr=config["lr"], lr_dist=config["lr_dist"],
-        cluster_scale=config["cluster_scale"], noise=config["noise"],
         beta=config["beta"], cache_duration=config["cache_duration"],
         quant_bits=int(codec[len("cache_delta+quant"):]),
         index_bytes=config["index_bytes"],

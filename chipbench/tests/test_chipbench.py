@@ -84,13 +84,13 @@ def test_metric_readers_on_a_synthesized_trace():
 
 def test_cost_of_the_2nn_by_hand():
     c = spec.load_cell("mnist-iid-scan-full").config
-    dims = cost.mlp_dims(c)
-    assert dims == [784, 200, 200, 10]
-    assert cost.param_count(dims) == 199_210            # FedAvg's MNIST 2NN
+    mlp = spec.part("family", c["family"])
+    assert mlp.dims(c) == [784, 200, 200, 10]
+    assert mlp.param_count(c) == 199_210                # FedAvg's MNIST 2NN
     macs = 784 * 200 + 200 * 200 + 200 * 10             # 198,800
-    assert cost.forward_flops(dims) == 2 * macs
+    assert mlp.forward_flops(c) == 2 * macs
     # forward + weight gradients + input gradients of layers 2 and 3
-    assert cost.train_step_flops(dims) == 2 * macs + 2 * macs + 2 * (200 * 200 + 200 * 10)
+    assert mlp.train_step_flops(c) == 2 * macs + 2 * macs + 2 * (200 * 200 + 200 * 10)
 
 
 def _files(config: str, traffic: str):
@@ -127,14 +127,13 @@ def test_round_cost_of_both_configurations_by_hand():
     # 805,263 samples over 3,548 clients, 62 classes, 100 clients a round
     fem_config = dict(c10_config, n_clients=3548, n_classes=62, private_size=805_263)
     fem_traffic = dict(c10_traffic, participants=100)
-    dims = cost.mlp_dims(fem_config)
-    assert cost.param_count(dims) == 209_662
-    rows = cost.uniform_rows(805_263, 3548)
+    assert spec.part("family", "mlp").param_count(fem_config) == 209_662
+    rows = spec.part("partition", "uniform").rows(805_263, 3548)
     assert rows.sum() == 805_263 and set(rows) == {226, 227}
-    assert set(cost.train_rows(805_263, 3548)) == {203, 204}   # int(0.9 n)
+    assert set(cost.train_rows(rows)) == {203, 204}            # int(0.9 n)
     fstep = 2 * (156_800 + 40_000 + 12_400) * 2 + 2 * (40_000 + 12_400)
     got = cost.round_flops(fem_config, fem_traffic, eval_round=False)
-    mean_train = cost.train_rows(805_263, 3548).mean()
+    mean_train = cost.train_rows(rows).mean()
     want_f = (100 * 5 * mean_train * fstep + 100 * 5 * 1000 * fstep
               + 100 * 1000 * 2 * 209_200 + 5 * 1000 * fstep)
     assert got == pytest.approx(want_f, rel=1e-12)
@@ -158,6 +157,9 @@ def test_every_config_mix_metric_and_cell_loads_by_name():
         for m in cell.per_layer:
             assert callable(spec.metric_reader(m["name"]))
         assert cell.config["name"] == w["config"]
+        parts = spec.parts(cell.config)
+        assert callable(parts["family"].logits) and callable(parts["inputs"].make)
+        assert callable(parts["partition"].shards)
     for c in bench["configs"]:
         assert set(c["reduced"]) <= set(json.loads((REPO / c["file"]).read_text()))
     with pytest.raises(KeyError):
@@ -201,6 +203,161 @@ def test_a_cell_from_new_files_only(tmp_path):
     assert cost.participants(cell.config, cell.traffic) == 5
     for p, data in before.items():
         assert p.read_bytes() == data, p
+
+
+# a client model over int32 token ids: the mean of their embedding rows,
+# then one linear layer; ``SEEN`` keeps the dtypes ``logits`` was given
+BAG_FAMILY = '''
+import jax
+import jax.numpy as jnp
+
+SEEN = set()
+
+
+def init(key, config, dtype):
+    k1, k2 = jax.random.split(key)
+    v, w, n = config["vocab"], config["width"], config["n_classes"]
+    return {"emb": (jax.random.normal(k1, (v, w)) * 0.5).astype(dtype),
+            "w": (jax.random.normal(k2, (w, n)) * 0.5).astype(dtype),
+            "b": jnp.zeros((n,), dtype)}
+
+
+def logits(p, x):
+    if not jnp.issubdtype(x.dtype, jnp.integer):
+        raise TypeError(f"token ids arrived as {x.dtype}")
+    SEEN.add(str(x.dtype))
+    return jnp.mean(p["emb"][x], axis=-2) @ p["w"] + p["b"]
+
+
+def forward_flops(config):
+    return 2 * config["width"] * config["n_classes"]
+
+
+def train_step_flops(config):
+    return 3 * forward_flops(config)
+'''
+
+# token ids whose range says the class
+TOKEN_INPUTS = '''
+import numpy as np
+
+
+def n_test(config):
+    return max(config["private_size"] // 5, 20)
+
+
+def make(config, seed):
+    rng = np.random.default_rng(seed)
+    n, per = config["n_classes"], config["vocab"] // config["n_classes"]
+
+    def draw(count):
+        y = rng.integers(0, n, size=count).astype(np.int32)
+        x = rng.integers(0, per, size=(count, config["seq_len"])) + y[:, None] * per
+        return x.astype(np.int32), y
+
+    xp, yp = draw(config["private_size"])
+    xu, _ = draw(config["public_size"])
+    xt, yt = draw(n_test(config))
+    return {"x_private": xp, "y_private": yp, "x_public": xu, "x_test": xt, "y_test": yt}
+'''
+
+# client k holds a share of the samples in proportion to k + 1
+UNEVEN_PARTITION = '''
+import numpy as np
+
+
+def rows(n_samples, n_clients):
+    w = np.arange(1, n_clients + 1)
+    r = (n_samples * w) // w.sum()
+    r[-1] += n_samples - r.sum()
+    return r.astype(np.int64)
+
+
+def shards(x, y, n_clients):
+    r = rows(len(y), n_clients)
+    n_max = int(r.max())
+    xs = np.zeros((n_clients, n_max) + x.shape[1:], x.dtype)
+    ys = np.zeros((n_clients, n_max), y.dtype)
+    valid = np.zeros((n_clients, n_max), bool)
+    lo = 0
+    for k, n in enumerate(r):
+        xs[k, :n], ys[k, :n], valid[k, :n] = x[lo:lo + n], y[lo:lo + n], True
+        lo += n
+    return xs, ys, valid
+'''
+
+
+def test_a_model_from_new_files_only(tmp_path):
+    """A later PR adds a client family, an inputs generator and a partition
+    by adding files: the reference trains the new model on integer token
+    ids, the FLOP count takes the family's per-sample counts and the
+    partition's rows, and no file that exists changes."""
+    from chipbench import reference, system
+    root = _copy_bench(tmp_path)
+    before = {p: p.read_bytes() for p in (root / "chipbench").rglob("*") if p.is_file()}
+    (root / "chipbench/families/bag.py").write_text(BAG_FAMILY)
+    (root / "chipbench/inputs/tokens.py").write_text(TOKEN_INPUTS)
+    (root / "chipbench/partitions/uneven.py").write_text(UNEVEN_PARTITION)
+    cfg = json.loads((root / "chipbench/configs/fedavg-mnist-iid-2nn.json").read_text())
+    for key in ("dim", "hidden", "mlp_depth", "cluster_scale", "noise"):
+        del cfg[key]
+    cfg.update(name="bag-tokens", family="bag", inputs="tokens", partition="uneven",
+               vocab=64, width=16, seq_len=12, n_classes=4, n_clients=6,
+               private_size=600, public_size=200, public_per_round=50)
+    (root / "chipbench/configs/bag-tokens.json").write_text(json.dumps(cfg))
+    (root / "chipbench/traffic/bag-mix.json").write_text(json.dumps(
+        {"engine": "scan", "participants": 3, "rounds_per_call": 2, "eval_every": 2,
+         "fused_round": False}))
+    (root / "chipbench/limits/bag-cell.json").write_text(json.dumps(
+        dict.fromkeys(compare.NAMES, 0.0)))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "bag-tokens", "source": "x", "reduced": [], "why": "x",
+                             "file": "chipbench/configs/bag-tokens.json"})
+    bench["workloads"].append({"name": "bag-cell", "config": "bag-tokens",
+                               "traffic": "bag-mix", "chips": 1, "why": "x"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cell = spec.load_cell("bag-cell", root)
+    ref = reference.Reference(system.reference_setting(cell.config, cell.traffic),
+                              seed=7, root=root)
+    assert ref.xs.dtype == np.int32 and ref.x_pub.dtype == np.int32
+    res = ref.run(2)
+    for change in (res.server_change, res.client_change):
+        assert set(change) == {"emb", "w", "b"}
+        assert all(np.isfinite(v) and v > 0 for v in change.values()), change
+    assert spec.part("family", "bag", root).SEEN == {"int32"}
+
+    # 3 of 6 clients, 5 local steps on 90% of each client's uneven rows,
+    # 5 distillation steps on 50 public rows, 50 predictions, the server's
+    # 5 steps; evaluation on the inputs file's 120 test rows
+    rows = np.array([28, 57, 85, 114, 142, 174])
+    assert list(spec.part("partition", "uneven", root).rows(600, 6)) == list(rows)
+    train = np.maximum((rows * 0.9).astype(int), 1)
+    fwd, step = 2 * 16 * 4, 3 * 2 * 16 * 4
+    want = 3 * 5 * train.mean() * step + 3 * 5 * 50 * step + 3 * 50 * fwd + 5 * 50 * step
+    got = cost.round_flops(cell.config, cell.traffic, eval_round=False, root=root)
+    assert got == pytest.approx(want, rel=1e-12)
+    ev = (120 + 120 + (600 - train.sum()) + 6 * 20 + 20) * fwd
+    got = cost.round_flops(cell.config, cell.traffic, eval_round=True, root=root)
+    assert got == pytest.approx(want + ev, rel=1e-12)
+    # a call of 2 rounds evaluates on its second; the run's readers get the root
+    call = cost.call_flops(cell.config, cell.traffic, root=root)
+    assert call == pytest.approx(2 * want + ev, rel=1e-12)
+    peak = spec.peaks("TPU v5 lite", root)
+    rec = {"trace": _trace(), "config": cell.config, "traffic": cell.traffic, "calls": 1,
+           "rounds": 2, "chips": 1, "peak": peak, "root": root}
+    assert spec.metric_reader("mfu.round", root)(rec) == pytest.approx(
+        100 * call / (100e-9 * peak["bf16_flops_per_s"]))
+    for p, data in before.items():
+        assert p.read_bytes() == data, p
+
+    unknown = dict(cell.config, family="no-such-family")
+    with pytest.raises(FileNotFoundError, match=str(root / "chipbench/families/no-such-family.py")):
+        reference.Reference(system.reference_setting(unknown, cell.traffic), seed=7, root=root)
+    lacking = {k: v for k, v in cell.config.items() if k != "inputs"}
+    (root / "chipbench/configs/bag-tokens.json").write_text(json.dumps(lacking))
+    with pytest.raises(KeyError, match="'inputs'"):
+        spec.load_cell("bag-cell", root)
 
 
 # -- no chip ---------------------------------------------------------------

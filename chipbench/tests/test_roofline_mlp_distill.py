@@ -4,7 +4,7 @@ device time of the ``mlp_distill`` instructions, and nothing where the
 kernel did not run."""
 import pytest
 
-from chipbench import cost, spec
+from chipbench import spec
 from chipbench import trace as tr
 
 
@@ -23,7 +23,7 @@ def test_roofline_mlp_distill_reads_the_kernel_instructions():
     # 100 clients x 5 steps x 1,000 rows x 879,200 operations: 439.6 GFLOP
     # a round, which bounds it (2.23 ms at 197 TFLOP/s against 0.19 ms for
     # the 159 MB of parameters read and written once)
-    flops = 100 * 5 * 1000 * cost.train_step_flops([784, 200, 200, 10])
+    flops = 100 * 5 * 1000 * spec.part("family", "mlp").train_step_flops(cell.config)
     assert flops == pytest.approx(439.6e9)
     want = 100 * (flops / 197e12) * 20 / 80e-9
     assert spec.metric_reader("roofline.mlp_distill")(rec) == pytest.approx(want)
