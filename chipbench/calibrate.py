@@ -38,14 +38,14 @@ def main(argv: Optional[List[str]] = None) -> int:
     from chipbench import run, system
 
     cell = spec.load_cell(args.workload)
-    run.devices_for(cell.chips, require_chip=True)
+    devs = run.devices_for(cell.chips, require_chip=True)
     run._enable_compile_cache()
     r = int(cell.traffic["rounds_per_call"])
     for seed in sorted(set(args.seeds) | set(args.control_seeds)):
         row = {"cell": cell.name, "seed": seed}
         if seed in args.seeds:
             t0 = time.perf_counter()
-            engine = system.build_engine(cell.config, cell.traffic, seed)
+            engine = system.build_engine(cell.config, cell.traffic, seed, devs)
             first, _ = run.first_call(engine, r)
             del engine
             gc.collect()
@@ -57,6 +57,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             row["program"] = compare.readings(first, ref)
             row["evals"] = {"program": first["evals"], "reference": ref.evals}
         if seed in args.control_seeds:
+            gc.collect()  # the float32 reference's device arrays, before the control's
             t0 = time.perf_counter()
             ctl = run.reference_result(cell, seed, dtype="bfloat16")
             row["control_s"] = time.perf_counter() - t0

@@ -36,9 +36,11 @@ class NoChip(RuntimeError):
 
 
 def devices_for(chips: int, require_chip: bool):
+    """The cell's first ``chips`` devices; TPUs unless ``require_chip`` is
+    false (the CPU tests)."""
     import jax
     devs = jax.devices()
-    if require_chip and (devs[0].platform != "tpu" or len(devs) < chips):
+    if len(devs) < chips or (require_chip and devs[0].platform != "tpu"):
         raise NoChip(f"this cell needs {chips} TPU chip(s); JAX found "
                      f"{len(devs)} {devs[0].platform} device(s)")
     return devs[:chips]
@@ -110,7 +112,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     r = int(cell.traffic["rounds_per_call"])
 
     t_build = time.perf_counter()
-    engine = system.build_engine(cell.config, cell.traffic, seed)
+    engine = system.build_engine(cell.config, cell.traffic, seed, devs)
     if engine_hook is not None:
         engine_hook(engine)
     t_first = time.perf_counter()
@@ -205,6 +207,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     except NoChip as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return EXIT_NO_CHIP
+    print(f"wall: {time.perf_counter() - t_start:.3f} s from the process's start to the result",
+          file=sys.stderr)
     for line in lines:
         print(line, file=sys.stderr)
     print(json.dumps(out))

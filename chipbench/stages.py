@@ -192,13 +192,13 @@ def main(argv=None) -> int:
 
     cell = spec.load_cell(args.workload)
     try:
-        run.devices_for(cell.chips, require_chip=True)
+        devs = run.devices_for(cell.chips, require_chip=True)
     except run.NoChip as e:
         print(f"chipbench.stages: {e}", file=sys.stderr)
         return run.EXIT_NO_CHIP
     run._enable_compile_cache()
     r = int(cell.traffic["rounds_per_call"])
-    engine = system.build_engine(cell.config, cell.traffic, args.seed)
+    engine = system.build_engine(cell.config, cell.traffic, args.seed, devs)
     for _ in range(1 + run.MAX_WARMUP_CALLS):
         engine.run(r)
     logdir = spec.REPO / ".chipbench_trace" / f"stages-{cell.name}-{args.seed}"

@@ -38,12 +38,17 @@ def engine_config(config: Dict[str, Any], traffic: Dict[str, Any], seed: int):
                     **{k: v for k, v in config.items() if k in fields})
 
 
-def build_engine(config: Dict[str, Any], traffic: Dict[str, Any], seed: int):
-    """The engine for one cell, constructed as ``run_method`` does.  The
-    program has no public constructor that ``run_method`` shares, so this
-    repeats its construction from the same engine table."""
+def build_engine(config: Dict[str, Any], traffic: Dict[str, Any], seed: int, devices):
+    """The engine for one cell, constructed as ``run_method`` does, on the
+    cell's ``devices``.  The program has no public constructor that
+    ``run_method`` shares, so this repeats its construction from the same
+    engine table.  The shard engine partitions its clients over a one-axis
+    mesh of exactly ``devices``; the others run on the first of them, the
+    default device."""
+    from jax.sharding import Mesh
     from repro.fl import Scenario, fixed_fraction, full_participation
     from repro.fl.api import _ENGINES
+    from repro.fl.shard_engine import CLIENT_AXIS
     from repro.fl.strategies import STRATEGIES
 
     cfg = engine_config(config, traffic, seed)
@@ -51,8 +56,10 @@ def build_engine(config: Dict[str, Any], traffic: Dict[str, Any], seed: int):
     part = full_participation() if rate >= 1.0 else fixed_fraction(rate)
     strategy = STRATEGIES[config["method"]](beta=config["beta"])
     cls = _ENGINES[traffic["engine"]]
+    kwargs = ({"mesh": Mesh(np.asarray(devices), (CLIENT_AXIS,))}
+              if traffic["engine"] == "shard" else {})
     return cls(cfg, strategy, cache_duration=config["cache_duration"],
-               scenario=Scenario(participation=part))
+               scenario=Scenario(participation=part), **kwargs)
 
 
 def client_leaves(engine, copy: bool = False) -> Dict[str, np.ndarray]:

@@ -1,6 +1,10 @@
 """CPU test set-up for the benchmark's own tests.
 
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest chipbench/tests
+
+The CPU backend makes 8 host devices, as ``tests/conftest.py`` has it,
+unless ``XLA_FLAGS`` already sets the count: a four-chip cell runs on 4
+of them.  The flag must be set before JAX makes its first device.
 """
 import os
 import sys
@@ -9,6 +13,9 @@ from pathlib import Path
 import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+_DEVICE_FLAG = "xla_force_host_platform_device_count"
+if _DEVICE_FLAG not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + f" --{_DEVICE_FLAG}=8").strip()
 REPO = Path(__file__).resolve().parents[2]
 for p in (REPO, REPO / "src"):
     if str(p) not in sys.path:

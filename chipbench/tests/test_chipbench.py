@@ -80,6 +80,29 @@ def test_metric_readers_on_a_synthesized_trace():
     assert spec.metric_reader("roofline.fused_round")(rec) is None
 
 
+def test_collective_ms_counts_only_exposed_collective_time():
+    # device 0: an async all-reduce pair around a fusion that hides all of
+    # the start's interval but 2 ns and the done's but 5 ns; a plain
+    # all-gather, alone, 4 ns; device 1: an all-reduce half under a fusion.
+    # Window 0..100 ns, 2 rounds.
+    dev0 = [_op("all-reduce-start.1", "all-reduce-start", 8, 14),
+            _op("fusion.2", "fusion", 10, 40),
+            _op("all-reduce-done.1", "all-reduce-done", 35, 45),
+            _op("all-gather.3", "all-gather", 50, 54),
+            _op("fusion.4", "fusion", 60, 90)]
+    dev1 = [_op("fusion.1", "fusion", 0, 20), _op("all-reduce.5", "all-reduce", 10, 30)]
+    rec = {"trace": tr.Trace({"/device:TPU:0": dev0, "/device:TPU:1": dev1}, [], (0, 100)),
+           "rounds": 2}
+    read = spec.metric_reader("collective_ms.shard")
+    # (2 + 5 + 4) ns on device 0, 10 ns on device 1: their mean over 2 rounds
+    assert read(rec) == pytest.approx((11 + 10) / 2 / 2 * 1e-6)
+    # a collective outside the window counts nothing; fusions alone give none
+    rec["trace"] = tr.Trace({"/device:TPU:0": dev0}, [], (60, 100))
+    assert read(rec) == 0.0
+    rec["trace"] = tr.Trace({"/device:TPU:0": [_op("fusion.1", "fusion", 0, 9)]}, [], (0, 10))
+    assert read(rec) is None
+
+
 # -- cost, by hand ---------------------------------------------------------
 
 def test_cost_of_the_2nn_by_hand():
@@ -102,8 +125,7 @@ def _files(config: str, traffic: str):
 
 def _c10():
     """The configuration under FedAvg's C=0.1: 10 of 100 clients a round."""
-    c, t = _files("fedavg-mnist-iid-2nn", "scan-all")
-    return c, dict(t, participants=10)
+    return _files("fedavg-mnist-iid-2nn", "scan-c10")
 
 
 def test_round_cost_of_both_configurations_by_hand():
@@ -125,8 +147,13 @@ def test_round_cost_of_both_configurations_by_hand():
 
     # a cross-device population of LEAF FEMNIST's shape (arXiv 1812.01097):
     # 805,263 samples over 3,548 clients, 62 classes, 100 clients a round
-    fem_config = dict(c10_config, n_clients=3548, n_classes=62, private_size=805_263)
-    fem_traffic = dict(c10_traffic, participants=100)
+    fem_config, fem_traffic = _files("leaf-femnist-2nn", "shard-m100")
+    assert {k: v for k, v in fem_config.items() if v != c10_config.get(k)}.keys() == {
+        "name", "source", "deployment", "assumed", "reduced", "reduced_why",
+        "n_clients", "n_classes", "private_size"}
+    assert (fem_config["n_clients"], fem_config["n_classes"],
+            fem_config["private_size"]) == (3548, 62, 805_263)
+    assert cost.participants(fem_config, fem_traffic) == 100
     assert spec.part("family", "mlp").param_count(fem_config) == 209_662
     rows = spec.part("partition", "uniform").rows(805_263, 3548)
     assert rows.sum() == 805_263 and set(rows) == {226, 227}
@@ -377,15 +404,30 @@ def test_no_tpu_exits_non_zero_without_a_result():
 TINY = dict(n_clients=8, private_size=2400, public_size=1000, public_per_round=100)
 
 
+# cells whose files are in place but that ``BENCHMARK.json`` holds back
+# (PERF.md, section 7): name -> (configuration, traffic mix, chips)
+HELD_BACK = {"femnist-shard-4chip": ("leaf-femnist-2nn", "shard-m100", 4)}
+
+
+def _load_cell(name: str) -> spec.Cell:
+    if name not in HELD_BACK:
+        return spec.load_cell(name)
+    config, traffic, chips = HELD_BACK[name]
+    c, t = _files(config, traffic)
+    limits = json.loads((REPO / "chipbench/limits" / f"{name}.json").read_text())
+    return spec.Cell(name=name, chips=chips, config_name=config, config=c,
+                     traffic_name=traffic, traffic=t, limits=limits, end_to_end=[],
+                     per_layer=[])
+
+
 def _tiny_cell(name: str, **traffic) -> spec.Cell:
     """The named cell at a size the CPU holds: its widths, codec and limits,
     with a small population and few rounds per call."""
-    cell = spec.load_cell(name)
+    cell = _load_cell(name)
     cell.config = dict(cell.config, **TINY)
     cell.traffic = dict(cell.traffic, rounds_per_call=4, eval_every=2, **traffic)
     if cell.traffic["participants"] != "all":
         cell.traffic["participants"] = 3
-    cell.chips = 1
     return cell
 
 
@@ -396,7 +438,8 @@ def _run(cell, hook=None):
     return out
 
 
-CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]]
+CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]] + list(HELD_BACK)
+SHARD_CELLS = [n for n in CELLS if _load_cell(n).traffic["engine"] == "shard"]
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -418,28 +461,40 @@ def test_bfloat16_control_fails_the_limits(name):
     assert not ok, table
 
 
-def _state_unchanged(engine):
-    """Every round hands back the state it was given: the scan body
-    returns its carry."""
-    inner = engine._round_device
-
-    def body(carry, xs):
-        _, ys = inner(carry, xs)
+def _returns_carry(inner):
+    def body(carry, *args):
+        _, ys = inner(carry, *args)
         return carry, ys
-    engine._round_device = body
+    return body
+
+
+def _state_unchanged(engine):
+    """Every round hands back the state it was given: the round body, the
+    scan engine's or the shard engine's, returns its carry."""
+    for name in ("_round_device", "_round_device_sharded"):
+        if hasattr(engine, name):
+            setattr(engine, name, _returns_carry(getattr(engine, name)))
 
 
 def _half_batch(engine):
-    """Aggregation over the first half of the participants only."""
+    """Aggregation over the participants among the first half of the
+    clients only (of each shard's clients, on the shard engine), in each
+    of the strategy's aggregation steps: SCARLET's single-device
+    ``aggregate_masked(_fused)`` do not call the partial steps that the
+    shard engine calls before its psum."""
     s = engine.strategy
 
     def halve(part):
         k = part.shape[0]
         return part * (np.arange(k) < k // 2)
     fused, plain = s.aggregate_masked_fused, s.aggregate_masked
+    pfused, pplain = s.partial_aggregate_fused, s.partial_aggregate
     s.aggregate_masked_fused = lambda z, part, spec_, base, t: fused(z, halve(part), spec_,
                                                                      base, t)
     s.aggregate_masked = lambda z, part, um, t: plain(z, halve(part), um, t)
+    s.partial_aggregate_fused = lambda z, part, spec_, base, t: pfused(z, halve(part), spec_,
+                                                                       base, t)
+    s.partial_aggregate = lambda z, part, um, t: pplain(z, halve(part), um, t)
 
 
 def _answer_altered(engine):
@@ -457,13 +512,55 @@ def _answer_altered(engine):
     engine.run = run
 
 
+def _no_exchange(engine):
+    """Each shard sharpens its own participants' partial sums: the psum of
+    the aggregation's moments between chips is left out.  The program's
+    other psums stay."""
+    import jax
+    psum, program = jax.lax.psum, engine._program
+
+    def local(x, axis_name, **kw):
+        return x if isinstance(x, dict) and "zsum" in x else psum(x, axis_name, **kw)
+
+    def patched():
+        fn = program()
+
+        def call(*args):
+            jax.lax.psum = local  # seen while the first call traces
+            try:
+                return fn(*args)
+            finally:
+                jax.lax.psum = psum
+        return call
+    engine._program = patched
+
+
 FAULTS = {"state_unchanged": _state_unchanged, "half_batch": _half_batch,
           "answer_altered": _answer_altered}
+SHARD_FAULTS = {"no_exchange": _no_exchange}
 
 
-@pytest.mark.parametrize("name,fault", [(n, f) for n in CELLS for f in FAULTS])
+@pytest.mark.parametrize("name,fault", [(n, f) for n in CELLS for f in FAULTS]
+                         + [(n, f) for n in SHARD_CELLS for f in SHARD_FAULTS])
 def test_planted_fault_is_not_correct(name, fault):
-    out = _run(_tiny_cell(name), FAULTS[fault])
+    out = _run(_tiny_cell(name), {**FAULTS, **SHARD_FAULTS}[fault])
     assert not out["correct"], out["checks"]
     if fault == "state_unchanged":
         assert out["checks"]["client_step"]["value"] == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("name", SHARD_CELLS)
+def test_shard_engine_mesh_is_the_cells_devices(name):
+    """The shard engine partitions its clients over exactly the devices it
+    is given, as many as the cell's chips, not over all of JAX's."""
+    import jax
+    from chipbench import system
+    cell = _tiny_cell(name)
+    devs = jax.devices()[-cell.chips:]
+    assert devs != jax.devices()[:cell.chips]
+    engine = system.build_engine(cell.config, cell.traffic, 7, devs)
+    assert list(engine.mesh.devices.flat) == devs
+    assert engine.mesh.axis_names == ("data",) and engine.n_shards == cell.chips
+    engine.run(1)
+    (stack,) = engine.client_params
+    assert {d for leaf in stack.values() for d in leaf.devices()} == set(devs)
