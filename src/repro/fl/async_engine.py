@@ -77,7 +77,6 @@ from repro.obs.trace import stage
 from repro.fl.scan_engine import ScannedFederatedDistillation
 from repro.fl.strategies.base import TRANSMIT_SALT
 from repro.fl.rounds import (
-    _select_cohorts,
     accuracy,
     accuracy_v,
     distill,
@@ -166,8 +165,7 @@ class AsyncFederatedDistillation(ScannedFederatedDistillation):
                 cp, x_prev, carry["prev_teacher"],
                 jnp.logical_and(dispatch, carry["have_prev"]))
         with stage("local_train"):
-            upd = self._local_train_all(cp, t)
-            cp = _select_cohorts(upd, cp, self.models.split(dispatch))
+            cp = self._train_selected(cp, t, dispatch)
 
         # --- request list at the ARRIVAL round's subset ------------------
         cache_prev = carry["cache"]

@@ -280,6 +280,9 @@ class FederatedDistillation:
         # clients per round whose distillation the traced round runs in
         # the VMEM kernel (set by _distill_all when the round is traced)
         self.distill_kernel_clients = 0
+        # client slots per round that the traced round's local training
+        # runs (set by _train_selected; the host loop trains every client)
+        self.local_train_clients = cfg.n_clients
         self._setup()
 
     # ------------------------------------------------------------------
@@ -545,22 +548,67 @@ class FederatedDistillation:
         return self.models.concat([predict_v(p, x) for p in params])
 
     # ------------------------------------------------------------------
-    def _local_train_all(self, params, t):
+    def _local_train_all(self, params, t, rows=None):
         """Per-cohort local training over the ``params`` list.  ``t``
-        may be a python int (host loop) or traced (scan)."""
+        may be a python int (host loop) or traced (scan).  ``rows[i]``,
+        where given and not ``None``, holds the client indices of cohort
+        ``i`` to train: those clients' params, shards and schedules are
+        gathered (indices past the cohort clip to its last client) and
+        the cohort's result is that block."""
         c = self.cfg
+        rows = rows or [None] * len(params)
+
+        def take(a, r):
+            return a if r is None else jnp.take(a, r, axis=0, mode="clip")
+
+        def args(i, p):
+            r = rows[i]
+            return (jax.tree_util.tree_map(lambda a: take(a, r), p),
+                    take(self.xs_c[i], r), take(self.ys_c[i], r),
+                    take(self.train_mask_c[i], r).astype(jnp.float32))
+
         if self.scenario.heterogeneity is None:
-            return [local_train_v(p, self.xs_c[i], self.ys_c[i],
-                                  self.train_mask_c[i].astype(jnp.float32),
-                                  c.lr, c.local_steps)
+            return [local_train_v(*args(i, p), c.lr, c.local_steps)
                     for i, p in enumerate(params)]
         decay = jnp.asarray(self._lr_decay, jnp.float32) ** (
             jnp.asarray(t, jnp.float32) - 1.0)
-        return [local_train_masked_v(p, self.xs_c[i], self.ys_c[i],
-                                     self.train_mask_c[i].astype(jnp.float32),
-                                     self._lr_k_c[i] * decay,
-                                     self._steps_k_c[i], self._max_steps)
+        return [local_train_masked_v(*args(i, p),
+                                     take(self._lr_k_c[i], rows[i]) * decay,
+                                     take(self._steps_k_c[i], rows[i]),
+                                     self._max_steps)
                 for i, p in enumerate(params)]
+
+    def _train_selected(self, params, t, keep):
+        """Local training of the clients in ``keep`` (``(K,)`` bool); the
+        others keep their params bit for bit.
+
+        Per cohort, at most ``cap_i = min(Scenario.max_participants(K),
+        n_i)`` clients can take part.  Where ``cap_i`` is the cohort's
+        size every client trains and ``_select`` keeps the participants'
+        results (the train-all program, unchanged).  Otherwise the
+        participants are gathered into a block of ``cap_i`` slots, the
+        block trains, and its rows are scattered back; a slot left empty
+        (a round with fewer participants) trains a duplicate and writes
+        nothing.  Local training draws no random numbers, so either way
+        each participant's update is the same.  The number of client
+        slots trained is recorded when the round is traced
+        (``local_train_clients``)."""
+        sizes = self.models.sizes
+        cap = self.scenario.max_participants(self.cfg.n_clients)
+        caps = [min(cap, n) for n in sizes]
+        self.local_train_clients = sum(caps)
+        rows = [None if cap_i == n
+                else jnp.nonzero(self.models.split(keep)[i], size=cap_i,
+                                 fill_value=n)[0]
+                for i, (cap_i, n) in enumerate(zip(caps, sizes))]
+        upd = self._local_train_all(params, t, rows)
+        # split after training, so that at full capacity the program is
+        # _select_cohorts(_local_train_all(...)) in the same order
+        keep_c = self.models.split(keep)
+        return [_select(u, p, k) if r is None
+                else jax.tree_util.tree_map(
+                    lambda a, b, r=r: a.at[r].set(b, mode="drop"), p, u)
+                for u, p, k, r in zip(upd, params, keep_c, rows)]
 
     # ------------------------------------------------------------------
     def _draw_round(self, t: int) -> Tuple[np.ndarray, np.ndarray]:

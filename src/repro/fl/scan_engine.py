@@ -44,7 +44,6 @@ from repro.fl.strategies.base import TRANSMIT_SALT
 from repro.fl.rounds import (
     FederatedDistillation,
     History,
-    _select_cohorts,
     accuracy,
     accuracy_v,
     distill,
@@ -138,15 +137,13 @@ class ScannedFederatedDistillation(FederatedDistillation):
 
         # --- clients: distill on previous teacher, then local training ----
         cp = carry["client_params"]
-        part_c = self.models.split(part)
         with stage("client_distill"):
             x_prev = self.x_pub[carry["prev_idx"]]
             cp = self._distill_all(
                 cp, x_prev, carry["prev_teacher"],
                 jnp.logical_and(part, carry["have_prev"]))
         with stage("local_train"):
-            upd = self._local_train_all(cp, t)
-            cp = _select_cohorts(upd, cp, part_c)
+            cp = self._train_selected(cp, t, part)
 
         # --- request list (cache) ----------------------------------------
         cache_prev = carry["cache"]
@@ -326,8 +323,8 @@ class ScannedFederatedDistillation(FederatedDistillation):
         ``engine.prepare`` (masks and the program's arguments),
         ``engine.dispatch`` (the call), ``engine.wait`` (until the
         device is done) and ``engine.finish`` (readback and History).
-        ``engine.run`` also carries ``distill_kernel_clients``, set once
-        the program is traced."""
+        ``engine.run`` also carries ``distill_kernel_clients`` and
+        ``local_train_clients``, set once the program is traced."""
         c = self.cfg
         T = c.rounds if rounds is None else rounds
         t0 = self.t_done  # absolute round numbering (chained/restored runs)
@@ -341,7 +338,8 @@ class ScannedFederatedDistillation(FederatedDistillation):
                 out = self._program()(*args)
                 del args  # the inputs are freed while the device runs
             run_span.set_metadata(
-                distill_kernel_clients=self.distill_kernel_clients)
+                distill_kernel_clients=self.distill_kernel_clients,
+                local_train_clients=self.local_train_clients)
             with span("engine.wait"):
                 # one execution defines every output: waiting on one leaf
                 # waits for the device, without a host wait per buffer

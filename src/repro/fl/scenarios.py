@@ -65,11 +65,20 @@ class Participation:
     kind: str = "full"
     rate: float = 1.0
 
+    def max_participants(self, n_clients: int) -> int:
+        """The most clients one draw can hold: the fixed count of
+        ``fraction``, every client otherwise."""
+        if self.kind == "fraction":
+            return min(max(int(round(self.rate * n_clients)), 1), n_clients)
+        if self.kind in ("full", "bernoulli"):
+            return n_clients
+        raise ValueError(f"unknown participation kind: {self.kind!r}")
+
     def sample(self, n_clients: int, rng: np.random.Generator) -> np.ndarray:
         if self.kind == "full":
             return np.ones(n_clients, bool)
         if self.kind == "fraction":
-            n = min(max(int(round(self.rate * n_clients)), 1), n_clients)
+            n = self.max_participants(n_clients)
             mask = np.zeros(n_clients, bool)
             mask[rng.choice(n_clients, n, replace=False)] = True
             return mask
@@ -88,7 +97,7 @@ class Participation:
         if self.kind == "full":
             return jnp.ones(n_clients, bool)
         if self.kind == "fraction":
-            n = min(max(int(round(self.rate * n_clients)), 1), n_clients)
+            n = self.max_participants(n_clients)
             sel = jax.random.choice(key, n_clients, (n,), replace=False)
             return jnp.zeros(n_clients, bool).at[sel].set(True)
         if self.kind == "bernoulli":
@@ -174,6 +183,15 @@ class Scenario:
         if rate >= 1.0:
             return cls(participation=full_participation())
         return cls(participation=fixed_fraction(rate))
+
+    def max_participants(self, n_clients: int) -> int:
+        """The most clients any round can take: the draw's bound, lifted
+        to ``min_participants`` by conscription.  Outage windows (and the
+        async engine's unreachable or in-flight clients) only remove
+        clients, so they leave the bound as it is."""
+        return min(n_clients,
+                   max(self.participation.max_participants(n_clients),
+                       self.min_participants))
 
     def offline_mask(self, t: int, n_clients: int) -> np.ndarray:
         off = np.zeros(n_clients, bool)

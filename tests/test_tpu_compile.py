@@ -109,3 +109,53 @@ def test_mlp_distill_compiles(one_chip, per_client):
     assert "tpu_custom_call" in text
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and "f32[100,784,200]" in ln.split(" copy(")[0]]
+
+
+def _stack_copies(text, stack):
+    return [ln for ln in text.splitlines()
+            if (" copy(" in ln or " copy-start(" in ln)
+            and stack in ln.split("=", 1)[1].split("(")[0]]
+
+
+def test_gathered_round_adds_no_stack_copy(one_chip, monkeypatch):
+    """The scan engine's 20-round program at FedAvg's 2NN widths, 10 of
+    100 clients a round (local training of a gathered block, scattered
+    back into the stack that ``mlp_distill`` updates in place), holds no
+    more layout copies of the f32[100,784,200] first-layer stack than
+    the same round that trains every client and selects.  The private
+    and public sets are cut, which the stack's layout does not see."""
+    import numpy as np
+    from repro.fl import (FLConfig, Scenario, ScannedFederatedDistillation,
+                          fixed_fraction)
+    from repro.fl.rounds import _select_cohorts
+    from repro.fl.scan_engine import compiler_options
+    from repro.fl.strategies import STRATEGIES
+
+    cfg = FLConfig(n_clients=100, n_classes=10, dim=784, hidden=200,
+                   mlp_depth=2, public_size=1000, public_per_round=1000,
+                   private_size=2000, rounds=20, eval_every=10,
+                   participation=0.1, fused_round=True,
+                   uplink_codec="cache_delta+quant8", seed=1)
+    eng = ScannedFederatedDistillation(
+        cfg, STRATEGIES["scarlet"](beta=1.5), cache_duration=50,
+        scenario=Scenario(participation=fixed_fraction(0.1)))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        eng._call_args(np.zeros(20, bool)))
+    # the backend the round is traced for decides the kernels' native
+    # lowering and the TPU compiler options
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def stack_copies():
+        fn = jax.jit(lambda carry, xs: jax.lax.scan(eng._round_device,
+                                                    carry, xs),
+                     compiler_options=compiler_options())
+        text = fn.lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+        return _stack_copies(text, "f32[100,784,200]")
+
+    gathered = stack_copies()
+    assert eng.local_train_clients == 10
+    eng._train_selected = lambda p, t, keep: _select_cohorts(
+        eng._local_train_all(p, t), p, eng.models.split(keep))
+    assert len(gathered) <= len(stack_copies()), gathered
